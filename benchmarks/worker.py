@@ -110,6 +110,8 @@ def _build_store_phase(payload):
 
 def main():
     payload = json.loads(sys.stdin.read())
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     from repro.configs.base import BFSConfig
     from repro.core.engine import plan_bfs
     from repro.core.ref import validate_parents

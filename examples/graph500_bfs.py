@@ -5,10 +5,12 @@ roots, report the harmonic-mean TEPS over pure per-root traversal time
 (compile/ship reported separately), validate every tree, compare comm
 volume to the §6 model.
 
-    PYTHONPATH=src python examples/graph500_bfs.py --scale 13 --grid 2x2
+    PYTHONPATH=src python examples/graph500_bfs.py --scale 13 --grid 1x1
 
-Multi-device grids need forced host devices, e.g.:
-    XLA_FLAGS=--xla_force_host_platform_device_count=16 \
+On a TPU host the grid runs on the chips (``--grid 2x2`` on a four-chip
+v5e host).  On the CPU, multi-device grids need forced host devices, and
+the Pallas kernels run in the interpreter:
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=16 \
         PYTHONPATH=src python examples/graph500_bfs.py --grid 4x4
 
 ``--decomposition 1d`` runs the paper's 1D row-strip baseline on
@@ -44,6 +46,7 @@ from repro.core.metrics import harmonic_mean, teps
 from repro.core.ref import validate_parents
 from repro.graph.formats import build_blocked, build_blocked_1d
 from repro.graph.rmat import random_source, rmat_graph
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_local_mesh, make_local_mesh_1d
 
 
@@ -70,6 +73,7 @@ def main():
                     help="GraphStore directory: persist graph + AOT "
                          "executable; identical reruns reload from disk")
     args = ap.parse_args()
+    use_compile_cache()
     pr, pc = map(int, args.grid.split("x"))
 
     store = None

@@ -137,7 +137,6 @@ def level_counts(plan, which: str) -> Dict[str, int]:
     from jax.sharding import PartitionSpec as P
 
     from repro.core import steps
-    from repro.core.compat import shard_map
     from repro.core.engine import hlo_collective_counts
 
     if plan.entry.level_steps is None:
@@ -162,10 +161,10 @@ def level_counts(plan, which: str) -> Dict[str, int]:
 
     spec = P(*plan.axes)
     gspec = {k: spec for k in plan.keys}
-    mapped = shard_map(fn, mesh=plan.mesh,
-                       in_specs=(gspec, spec, spec, P()),
-                       out_specs=(spec, {k: P() for k in ctr_keys}),
-                       check_vma=False)
+    mapped = jax.shard_map(fn, mesh=plan.mesh,
+                           in_specs=(gspec, spec, spec, P()),
+                           out_specs=(spec, {k: P() for k in ctr_keys}),
+                           check_vma=False)
     arrs = _graph_sds(plan)
     pi = jax.ShapeDtypeStruct(arrs["deg_A"].shape, np.int32)
     fr = jax.ShapeDtypeStruct(arrs["deg_A"].shape, np.bool_)

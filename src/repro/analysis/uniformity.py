@@ -45,10 +45,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
 
-try:  # jax 0.4.x public core; newer jax moved these under jax.extend
-    from jax.core import ClosedJaxpr, Jaxpr, Literal  # type: ignore
-except ImportError:  # pragma: no cover - newer jax
-    from jax._src.core import ClosedJaxpr, Jaxpr, Literal  # type: ignore
+from jax.extend.core import ClosedJaxpr, Jaxpr, Literal
 
 # collectives that rendezvous between devices (jaxpr primitive names)
 REDUCTIONS = ("psum", "pmax", "pmin")
@@ -268,12 +265,16 @@ class _Walker:
     def _eval_shard_map(self, env, eqn, preds, path, record, seq):
         params = eqn.params
         inner = _as_closed(params["jaxpr"])
-        in_names = params["in_names"]
+        manual = frozenset(params["manual_axes"])
         in_vals = []
-        for names in in_names:
+        for spec in params["in_specs"]:
+            # axes named by the input's PartitionSpec that the body sees
+            # as manual: the per-device blocks differ along exactly these
             used = set()
-            for ax_tuple in names.values():
-                used.update(_norm_axes(ax_tuple))
+            for entry in spec:
+                if entry is not None:
+                    used.update(_norm_axes(entry))
+            used &= manual
             if used:
                 in_vals.append(AbstractVal(
                     self.full - used,
@@ -386,7 +387,7 @@ def analyze_jaxpr(closed_jaxpr, mesh_axes: Sequence[str],
     included for batched programs).  Top-level inputs default to
     uniform-everywhere, which matches host-level values entering a
     jitted program before any shard_map (the shard_map eqn re-seeds
-    its body's inputs from ``in_names``); pass explicit ``in_vals``
+    its body's inputs from ``in_specs``); pass explicit ``in_vals``
     when analyzing a bare shard_map *body* jaxpr directly."""
     w = _Walker(mesh_axes)
     cj = _as_closed(closed_jaxpr)

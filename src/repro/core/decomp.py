@@ -81,6 +81,8 @@ class PlanStatics:
     instrument: bool = True   # False: compile counters/level_stats OUT
     #                           of the search program (the latency-lean
     #                           fast path; parents identical)
+    interpret: bool = False   # Pallas kernels in the interpreter: derived
+    #                           from the plan's mesh (True only on CPU)
 
 
 @dataclass(frozen=True)
@@ -412,7 +414,8 @@ def _make_args_2d(part, cfg, ops, axes, statics: PlanStatics) -> LevelArgs:
                      use_edge_dst=cfg.use_edge_dst,
                      compact_updates=cfg.compact_updates, ops=ops,
                      instrument=statics.instrument,
-                     expand_chunks=statics.expand_chunks)
+                     expand_chunks=statics.expand_chunks,
+                     interpret=statics.interpret)
 
 
 def _validate_2d(part, statics: PlanStatics) -> None:
@@ -507,7 +510,8 @@ def _make_args_1d(part, cfg, ops, axes, statics: PlanStatics) -> LevelArgs1D:
                        local_mode=ops.local_mode, storage=cfg.storage,
                        cap_f=statics.cap_f, maxdeg=statics.maxdeg, ops=ops,
                        instrument=statics.instrument,
-                       expand_chunks=statics.expand_chunks)
+                       expand_chunks=statics.expand_chunks,
+                       interpret=statics.interpret)
 
 
 def _validate_strip_chunks(part, statics: PlanStatics) -> None:
@@ -557,7 +561,8 @@ def _make_args_1ds(part, cfg, ops, axes,
                         cap_f=statics.cap_f, maxdeg=statics.maxdeg, ops=ops,
                         instrument=statics.instrument,
                         codec=cfg.frontier_codec,
-                        expand_chunks=statics.expand_chunks)
+                        expand_chunks=statics.expand_chunks,
+                        interpret=statics.interpret)
 
 
 def _validate_1ds(part, statics: PlanStatics) -> None:

@@ -47,7 +47,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import BFSConfig
 from repro.core import comm_model
-from repro.core.compat import shard_map
 from repro.core.decomp import (Decomposition, PlanStatics,
                                get_decomposition)
 from repro.core.local_ops import LocalOps, get_local_ops
@@ -127,7 +126,7 @@ class BFSPlan:
                 return inner(g, root)
 
         gspec = {k: self.entry.graph_spec(self.axes) for k in self.keys}
-        mapped = shard_map(
+        mapped = jax.shard_map(
             body, mesh=self.mesh,
             in_specs=(gspec, P()),
             out_specs=self.entry.out_specs(self.axes,
@@ -165,7 +164,7 @@ class BFSPlan:
             return pis.reshape((1,) * n_axes + pis.shape), levels, stats
 
         gspec = {k: self.entry.graph_spec(self.axes) for k in self.keys}
-        mapped = shard_map(
+        mapped = jax.shard_map(
             multi_body, mesh=self.mesh,
             in_specs=(gspec, P(pod_axis)),
             out_specs=self.entry.batch_out_specs(self.axes, pod_axis),
@@ -237,10 +236,15 @@ def plan_for_part(part, cfg: BFSConfig, mesh, *,
             f"cfg.expand_chunks={cfg.expand_chunks} must be >= 1 "
             f"(1 = unpipelined expand)")
     ops = get_local_ops(cfg.decomposition, local_mode, cfg.storage)
+    # Pallas kernels run in the interpreter only on a CPU mesh: read off
+    # the mesh the plan compiles for, never the process default backend,
+    # so a plan for a described TPU topology compiles them with Mosaic
+    interpret = mesh.devices.flat[0].platform == "cpu"
     statics = PlanStatics(cap_seg=cap_seg, maxdeg=maxdeg, cap_f=cap_f,
                           cap_x=cap_x, n_real_edges=n_real_edges,
                           instrument=cfg.instrument,
-                          expand_chunks=cfg.expand_chunks)
+                          expand_chunks=cfg.expand_chunks,
+                          interpret=interpret)
     entry.validate(part, statics)
     return BFSPlan(part=part, cfg=cfg, mesh=mesh, entry=entry, ops=ops,
                    axes=axes, statics=statics)

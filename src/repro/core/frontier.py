@@ -74,7 +74,7 @@ def expand_bitmap(front_chunk: jax.Array, perm, axes) -> Tuple[jax.Array, jax.Ar
     words = pack_bits(front_chunk)
     words_b = transpose_vector(words, perm, axes)
     gathered = lax.all_gather(words_b, row_axis, tiled=True)
-    pr = lax.psum(1, row_axis)  # static axis size (lax.axis_size is newer jax)
+    pr = lax.axis_size(row_axis)
     wire = jnp.float32(words.size) * (1.0 / 2.0) * (1 + (pr - 1))
     # 1/2: uint32 word = half a 64-bit paper word. transpose sends 1 copy,
     # allgather sends (pr-1) copies of each word.

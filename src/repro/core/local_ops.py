@@ -33,14 +33,16 @@ Closure signatures (all arrays squeezed to the local block/strip):
 
   topdown(g, f_words, f_mask, nr, col_offset, args)
       -> (cand (nr,) i32 candidate parents, edges_examined_local f32)
-  bottomup(rp_seg, ue_win, f_words, cvec, col_offset, n_edges, ve_win)
+  bottomup(rp_seg, ue_win, f_words, cvec, col_offset, n_edges, ve_win,
+           args)
       -> (chunk,) i32 newly discovered parents (INT_INF = none)
 
 ``f_words`` is the packed frontier bitmap over the block's column range
 (uint32 words), ``f_mask`` its unpacked bool form; 2D passes the C_j
 slice with col_offset = j*nc, 1D passes the full allgathered frontier
 with col_offset = 0 (strip ids are global).  ``args`` is the LevelArgs /
-LevelArgs1D NamedTuple (cap_f, maxdeg statics).
+LevelArgs1D NamedTuple (cap_f, maxdeg statics, and ``interpret``: the
+plan runs the Pallas kernels in the interpreter only on a CPU mesh).
 """
 from __future__ import annotations
 
@@ -132,10 +134,10 @@ def _td_kernel_csr(g, f_words, f_mask, nr, col_offset, args):
     exceeds: larger frontiers are silently truncated) to shrink it."""
     from repro.kernels.spmsv import ops as spmsv_ops
     cap_f = args.cap_f or f_mask.shape[0]
-    ridx = jnp.pad(g["row_idx"], (0, 256))
-    cand = spmsv_ops.spmsv_block_csr(g["col_ptr"], ridx, f_mask, nr,
+    cand = spmsv_ops.spmsv_block_csr(g["col_ptr"], g["row_idx"], f_mask, nr,
                                      col_offset, cap_f=cap_f,
-                                     maxdeg=args.maxdeg)
+                                     maxdeg=args.maxdeg,
+                                     interpret=args.interpret)
     ex = jnp.sum(jnp.where(f_mask, g["col_ptr"][1:] - g["col_ptr"][:-1], 0),
                  dtype=jnp.float32)
     return cand, ex
@@ -155,10 +157,10 @@ def _td_kernel_dcsc_2d(g, f_words, f_mask, nr, col_offset, args):
     search — the paper's hypersparse indirection cost, Fig. 6."""
     from repro.kernels.spmsv import ops as spmsv_ops
     cap_f = args.cap_f or f_mask.shape[0]
-    ridx = jnp.pad(g["row_idx"], (0, 256))
-    cand = spmsv_ops.spmsv_block_dcsc(g["jc"], g["cp"], g["nzc"], ridx,
-                                      f_mask, nr, col_offset, cap_f=cap_f,
-                                      maxdeg=args.maxdeg)
+    cand = spmsv_ops.spmsv_block_dcsc(g["jc"], g["cp"], g["nzc"],
+                                      g["row_idx"], f_mask, nr, col_offset,
+                                      cap_f=cap_f, maxdeg=args.maxdeg,
+                                      interpret=args.interpret)
     return cand, _dcsc_edges_examined(g["jc"], g["cp"], g["nzc"], f_mask)
 
 
@@ -167,45 +169,31 @@ def _td_strip_dcsc(g, f_words, f_mask, nr, col_offset, args):
     against the allgathered frontier bitmap (kernels/spmsv/strip.py) —
     no O(n) pointer array and no per-frontier-vertex search."""
     from repro.kernels.spmsv import ops as spmsv_ops
-    ridx = jnp.pad(g["row_idx"], (0, 256))
-    cand = spmsv_ops.spmsv_strip_dcsc(g["jc"], g["cp"], g["nzc"], ridx,
-                                      f_words, nr, maxdeg=args.maxdeg)
+    cand = spmsv_ops.spmsv_strip_dcsc(g["jc"], g["cp"], g["nzc"],
+                                      g["row_idx"], f_words, nr,
+                                      maxdeg=args.maxdeg,
+                                      interpret=args.interpret)
     return cand, _dcsc_edges_examined(g["jc"], g["cp"], g["nzc"], f_mask)
-
-
-def _dcsc_edges_examined_chunk(jc, cp, nzc, g_sub, k, n_chunks, chunk, n):
-    """Frontier-column segment-length sum for ONE pipelined sub-chunk:
-    bitmap-tests each column id against the raw owner-major sub-chunk
-    buffer (no full-size bitmap), so the per-chunk sums add up exactly
-    to the unchunked ``_dcsc_edges_examined``."""
-    wpc = chunk // 32
-    w_sub = wpc // n_chunks
-    slot = jnp.arange(jc.shape[0])
-    uc = jnp.minimum(jc, n - 1)
-    wi = uc >> 5
-    owner = wi // wpc
-    lw = wi - owner * wpc
-    in_rng = (lw >= k * w_sub) & (lw < (k + 1) * w_sub)
-    pos = jnp.where(in_rng, owner * w_sub + (lw - k * w_sub), 0)
-    bit = ((g_sub[pos] >> (uc.astype(jnp.uint32) & jnp.uint32(31)))
-           & jnp.uint32(1)) == 1
-    live = (slot < nzc) & (jc < n) & in_rng & bit
-    return jnp.sum(jnp.where(live, cp[1:] - cp[:-1], 0), dtype=jnp.float32)
 
 
 def _td_strip_dcsc_chunk(g, g_sub, k, n_chunks, nr, col_offset, args):
     """Per-chunk entry of the strip SpMSV for the software-pipelined
-    expand: the Pallas kernel consumes the raw gathered sub-chunk buffer
+    expand: the gather consumes the raw gathered sub-chunk buffer
     directly (kernels/spmsv/strip.py chunk entry point); the caller
-    min-combines candidates across chunks."""
+    min-combines candidates across chunks.  The edges-examined sum tests
+    the same per-chunk column liveness, so the per-chunk sums add up
+    exactly to the unchunked ``_dcsc_edges_examined``."""
     from repro.kernels.spmsv import ops as spmsv_ops
+    from repro.kernels.spmsv.strip import strip_live_columns_chunk
     part = args.part
-    ridx = jnp.pad(g["row_idx"], (0, 256))
+    jc, cp = g["jc"], g["cp"]
     cand = spmsv_ops.spmsv_strip_dcsc_chunk(
-        g["jc"], g["cp"], g["nzc"], ridx, g_sub, nr, n=part.n, p=part.p,
-        k=k, n_chunks=n_chunks, maxdeg=args.maxdeg)
-    ex = _dcsc_edges_examined_chunk(g["jc"], g["cp"], g["nzc"], g_sub, k,
-                                    n_chunks, part.chunk, part.n)
+        jc, cp, g["nzc"], g["row_idx"], g_sub, nr, n=part.n, p=part.p,
+        k=k, n_chunks=n_chunks, maxdeg=args.maxdeg,
+        interpret=args.interpret)
+    live = strip_live_columns_chunk(jc, g["nzc"], g_sub, n=part.n, p=part.p,
+                                    k=k, n_chunks=n_chunks)
+    ex = jnp.sum(jnp.where(live, cp[1:] - cp[:-1], 0), dtype=jnp.float32)
     return cand, ex
 
 
@@ -214,20 +202,22 @@ def _td_strip_dcsc_chunk(g, g_sub, k, n_chunks, nr, col_offset, args):
 # ---------------------------------------------------------------------------
 
 
-def _bu_ref(rp_seg, ue_win, f_words, cvec, col_offset, n_edges, ve_win):
+def _bu_ref(rp_seg, ue_win, f_words, cvec, col_offset, n_edges, ve_win,
+            args):
     from repro.kernels.bottomup.ref import bottomup_substep
     return bottomup_substep(rp_seg, ue_win, f_words, cvec, col_offset,
                             n_edges, ve_win=ve_win)
 
 
-def _bu_kernel(rp_seg, ue_win, f_words, cvec, col_offset, n_edges, ve_win):
+def _bu_kernel(rp_seg, ue_win, f_words, cvec, col_offset, n_edges, ve_win,
+               args):
     """Pallas tile-granular early-exit scan; per-edge rows come from the
     CSR pointers inside the kernel, so ve_win is unused."""
     from repro.kernels.bottomup import ops as bu_ops
     chunk = rp_seg.shape[0] - 1
-    return bu_ops.bottomup_substep(rp_seg, jnp.pad(ue_win, (0, 512)),
-                                   f_words, cvec, col_offset, n_edges,
-                                   rt=min(128, chunk))
+    return bu_ops.bottomup_substep(rp_seg, ue_win, f_words, cvec, col_offset,
+                                   n_edges, rt=min(128, chunk),
+                                   interpret=args.interpret)
 
 
 # ---------------------------------------------------------------------------

@@ -27,7 +27,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.core.compat import shard_map
 from repro.core.partition import Partition2D
 from repro.graph.formats import BlockedGraph
 
@@ -57,7 +56,7 @@ def make_spmm_fn(mesh, part: Partition2D, row_axis: str = "data",
                              perm=tuple(part.transpose_perm()),
                              row_axis=row_axis, col_axis=col_axis)
     spec = P(row_axis, col_axis)
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=({k: spec for k in ("edge_src", "row_idx", "nnz")}, spec),
         out_specs=spec, check_vma=False)

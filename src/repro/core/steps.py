@@ -59,6 +59,7 @@ class LevelArgs(NamedTuple):
     # pipelined R/G split ring (see bottomup_level); the value itself is
     # a toggle for 2D — the chunk count only shapes the 1d/1ds expand
     expand_chunks: int = 1
+    interpret: bool = False   # Pallas interpreter (CPU mesh) vs Mosaic
 
 
 def _resolve_ops(args: "LevelArgs"):
@@ -368,7 +369,7 @@ def bottomup_level(g: Dict[str, jax.Array], pi: jax.Array, front: jax.Array,
               - seg_id * chunk) if args.use_edge_dst and "edge_dst" in g \
             else None
         seg_par = ops.bottomup(rp_seg, ue, f_words, cvec, col_offset,
-                               n_edges, ve)
+                               n_edges, ve, args)
         found = seg_par != INT_INF
         if pipelined:
             # exactness post-filter: the scan above used the stale

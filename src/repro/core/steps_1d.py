@@ -57,6 +57,7 @@ class LevelArgs1D(NamedTuple):
     # many sub-chunk collectives, consuming sub-chunk k while k+1 is in
     # flight (1 = the classic single-gather schedule)
     expand_chunks: int = 1
+    interpret: bool = False   # Pallas interpreter (CPU mesh) vs Mosaic
 
 
 def _resolve_ops(args: "LevelArgs1D"):
@@ -73,7 +74,7 @@ def expand_frontier_1d(front: jax.Array, axis: str):
     wire/use expand words in paper 64-bit units)."""
     words = pack_bits(front)                         # (chunk//32,) u32
     gathered = lax.all_gather(words, axis, tiled=True)
-    p = lax.psum(1, axis)   # static axis size (lax.axis_size needs newer jax)
+    p = lax.axis_size(axis)
     # shared closed form (word-size conversion lives in comm_model, so
     # the measured counter and the model cannot drift): n = chunk * p
     wire = jnp.float32(comm_model.expand_1d_level_words(words.size * 32 * p, p))
@@ -225,7 +226,7 @@ def bottomup_level_1d(g: Dict[str, jax.Array], pi: jax.Array,
     ve = g["edge_dst"] if args.use_edge_dst and "edge_dst" in g else None
     seg_par = _resolve_ops(args).bottomup(g["row_ptr"], g["col_idx"],
                                           f_words, cvec, jnp.int32(0),
-                                          g["nnz"], ve)
+                                          g["nnz"], ve, args)
     newly = (pi == -1) & (seg_par != INT_INF)
     pi = jnp.where(newly, seg_par, pi)
 

@@ -65,6 +65,7 @@ Two pre-wire reductions from the literature sit on top:
 """
 from __future__ import annotations
 
+import functools
 from typing import Dict, NamedTuple, Tuple
 
 import jax
@@ -101,12 +102,13 @@ class LevelArgs1DS(NamedTuple):
     # each consumed while the next is in flight (1 = classic schedule);
     # must divide chunk/32 and cap_x (plan_bfs validates)
     expand_chunks: int = 1
+    interpret: bool = False   # Pallas interpreter (CPU mesh) vs Mosaic
 
 
 def sparse_exchange_1d(front: jax.Array, axis: str, cap_x: int, part,
                        over=None, instrument: bool = True,
                        visited=None, codec: str = "none",
-                       use_kernel: bool = False):
+                       use_kernel: bool = False, interpret: bool = False):
     """Owner-directed sparse frontier exchange with dense fallback.
 
     Each processor compacts its owned frontier chunk into a
@@ -132,7 +134,8 @@ def sparse_exchange_1d(front: jax.Array, axis: str, cap_x: int, part,
 
     ``codec="packed"`` bit-packs the bucket (count word + local offsets
     at ``codec_bits(chunk)`` bits each; ``kernels/frontier_codec``,
-    Pallas when ``use_kernel`` else the jnp oracle).  Same single
+    Pallas when ``use_kernel`` else the jnp oracle; ``interpret`` runs
+    the Pallas kernels in the interpreter).  Same single
     allgather — the count rides inside the buffer — so the collective
     budget is unchanged; only the bytes shrink.
 
@@ -163,10 +166,12 @@ def sparse_exchange_1d(front: jax.Array, axis: str, cap_x: int, part,
     if codec == "packed":
         from repro.kernels.frontier_codec import ops as codec_ops
         from repro.kernels.frontier_codec import ref as codec_ref
-        enc = codec_ops.encode_offsets if use_kernel \
+        enc = functools.partial(codec_ops.encode_offsets,
+                                interpret=interpret) if use_kernel \
             else codec_ref.encode_offsets
         dec = (lambda r: codec_ops.decode_buckets(
-                   r, part.chunk, cap_x, part.n, p)) if use_kernel \
+                   r, part.chunk, cap_x, part.n, p, interpret=interpret)) \
+            if use_kernel \
             else (lambda r: codec_ref.decode_buckets(
                       r, part.chunk, cap_x, part.n))
 
@@ -245,10 +250,11 @@ def _pipelined_topdown_1ds(g, send: jax.Array, over, args: "LevelArgs1DS"):
     if args.codec == "packed":
         from repro.kernels.frontier_codec import ops as codec_ops
         from repro.kernels.frontier_codec import ref as codec_ref
-        enc = codec_ops.encode_offsets if use_kernel \
+        enc = functools.partial(codec_ops.encode_offsets,
+                                interpret=args.interpret) if use_kernel \
             else codec_ref.encode_offsets
-        dec = (lambda r: codec_ops.decode_buckets(r, sub, cap_c,
-                                                  p * sub, p)) \
+        dec = (lambda r: codec_ops.decode_buckets(
+                   r, sub, cap_c, p * sub, p, interpret=args.interpret)) \
             if use_kernel \
             else (lambda r: codec_ref.decode_buckets(r, sub, cap_c,
                                                      p * sub))
@@ -327,7 +333,8 @@ def topdown_level_1ds(g: Dict[str, jax.Array], pi: jax.Array,
         f_words, wire, _ = sparse_exchange_1d(
             front, args.axis, args.cap_x, part, over=over,
             instrument=instr, visited=visited, codec=args.codec,
-            use_kernel=(args.local_mode == "kernel"))
+            use_kernel=(args.local_mode == "kernel"),
+            interpret=args.interpret)
         f_all = unpack_bits(f_words)                 # (n,) bool
         # --- Local discovery: unchanged from "1d" (same LocalOps
         # entries) --

@@ -65,8 +65,6 @@ from jax import lax
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.core.compat import shard_map
-
 from repro.core.decomp import MAX_LEVELS
 
 CHECKS = ("root_self_parent", "tree_edge_missing", "parent_chain_broken",
@@ -200,9 +198,9 @@ def build_validate_fn(plan):
         return lax.psum(counts, axes)
 
     gspec = {k: P(*axes) for k in entry.edge_keys}
-    mapped = shard_map(body, mesh=plan.mesh,
-                       in_specs=(gspec, P(*axes), P()),
-                       out_specs=P(), check_vma=False)
+    mapped = jax.shard_map(body, mesh=plan.mesh,
+                           in_specs=(gspec, P(*axes), P()),
+                           out_specs=P(), check_vma=False)
     return jax.jit(mapped)
 
 

@@ -53,7 +53,6 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from repro.core import comm_model
-from repro.core.compat import shard_map
 from repro.core.partition import make_partition, make_partition_1d
 from repro.graph.formats import Blocked1DGraph, BlockedGraph, _round_up
 from repro.graph.rmat import rmat_edges_counter, rmat_edges_counter_jax
@@ -231,10 +230,10 @@ def dist_build_1d(spec: BuildSpec, p: int, mesh, *, align: int = 128,
     def dest_base(k):
         return jnp.asarray(k, jnp.int32) * chunk
 
-    p1 = jax.jit(shard_map(phase1, mesh=mesh, in_specs=(),
-                           out_specs=(P(row_axis), P(row_axis),
-                                      P(row_axis), P(row_axis)),
-                           check_vma=False))
+    p1 = jax.jit(jax.shard_map(phase1, mesh=mesh, in_specs=(),
+                               out_specs=(P(row_axis), P(row_axis),
+                                          P(row_axis), P(row_axis)),
+                               check_vma=False))
     t0 = time.perf_counter()
     cu_all, cv_all, deg_all, stats_all = p1()
     stats = np.asarray(stats_all)                # (p, 5) scalars only
@@ -270,7 +269,7 @@ def dist_build_1d(spec: BuildSpec, p: int, mesh, *, align: int = 128,
                 one(edge_dst), one(jc), one(cp), nnz_l.reshape(1),
                 nzc_l.reshape(1), one(deg))
 
-    p2 = jax.jit(shard_map(
+    p2 = jax.jit(jax.shard_map(
         phase2, mesh=mesh,
         in_specs=(P(row_axis), P(row_axis), P(row_axis)),
         out_specs=tuple(P(row_axis) for _ in range(10)),
@@ -382,9 +381,9 @@ def dist_build_2d(spec: BuildSpec, pr: int, pc: int, mesh, *,
                 stats.reshape(1, 1, -1))
 
     axes = (row_axis, col_axis)
-    p1 = jax.jit(shard_map(phase1, mesh=mesh, in_specs=(),
-                           out_specs=tuple(P(*axes) for _ in range(4)),
-                           check_vma=False))
+    p1 = jax.jit(jax.shard_map(phase1, mesh=mesh, in_specs=(),
+                               out_specs=tuple(P(*axes) for _ in range(4)),
+                               check_vma=False))
     t0 = time.perf_counter()
     cu_all, cv_all, deg_all, stats_all = p1()
     stats = np.asarray(stats_all).reshape(p, -1)
@@ -431,7 +430,7 @@ def dist_build_2d(spec: BuildSpec, pr: int, pc: int, mesh, *,
                 one(cp), one(jr), one(rp), nnz_l.reshape(1, 1),
                 nzc_l.reshape(1, 1), nzr_l.reshape(1, 1), one(deg))
 
-    p2 = jax.jit(shard_map(
+    p2 = jax.jit(jax.shard_map(
         phase2, mesh=mesh, in_specs=tuple(P(*axes) for _ in range(3)),
         out_specs=tuple(P(*axes) for _ in range(15)),
         check_vma=False))

@@ -175,13 +175,13 @@ def rmat_edges_counter_jax(scale: int, count: int, start,
 def rmat_edges_counter_kernel(scale: int, count: int, start,
                               edge_factor: int = 16, a: float = 0.57,
                               b: float = 0.19, c: float = 0.19,
-                              seed: int = 1, tile: int = 4096,
-                              interpret: bool = True):
+                              seed: int = 1, tile: int = 4096, *,
+                              interpret: bool):
     """Pallas build of the per-shard counter generator: a grid program
     over ``tile``-edge blocks, each an independent VPU-width batch of
     uint32 mixing (no cross-tile state — the whole point of the
-    counter RNG).  Bit-identical to the jnp/numpy twins; kept
-    ``interpret=True`` by default for CPU CI, matching kernels/*.
+    counter RNG).  Bit-identical to the jnp/numpy twins.  ``interpret``
+    selects the Pallas interpreter (CPU) or a Mosaic compile (TPU).
 
     The TPU core PRNG (pltpu.prng_random_bits) is deliberately NOT used:
     its stream depends on how work is split over cores, which would

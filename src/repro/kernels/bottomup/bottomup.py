@@ -4,27 +4,30 @@ TPU adaptation of the paper's serialized inner loop:
 
   * The per-vertex "scan neighbors until a parent is found, then stop"
     early exit is hostile to SIMD, so it is restructured at *tile*
-    granularity: a VMEM-resident row tile (RT rows) scans its contiguous
-    CSR edge window in ET-edge tiles inside a ``lax.while_loop`` whose
+    granularity: a row tile (RT rows) scans its contiguous CSR edge
+    window in 1024-edge tiles inside a ``lax.while_loop`` whose
     predicate stops as soon as EVERY live row in the tile has found a
     parent (or the window is exhausted).  The work skip the paper gets
     from ``break`` is preserved — whole edge tiles are never touched once
     the row tile completes — while each tile step stays fully vectorized
-    on the VPU (8x128 lanes).
-  * Frontier membership is a packed uint32 bitmap held in VMEM (the
-    paper's §4.3 "dense format compressed by a bitmap" — constant-time
-    tests with zero network crossings); tests are vector gathers.
+    on the VPU: rows on sublanes, edges on lanes, one (RT, 1024)
+    row-membership mask per tile.
+  * Frontier membership is a packed uint32 bitmap (the paper's §4.3
+    "dense format compressed by a bitmap").  Testing it is a
+    data-dependent gather, which the TPU compiler refuses inside a
+    kernel, so XLA runs it around the ``pallas_call``: the kernel reads
+    each window edge's candidate parent (``col_offset + u`` when u is in
+    the frontier, INT_INF otherwise) from a lane-dense (R, 128) array.
   * ``completed`` rows are masked out up front, so rotated-in work that
     earlier sub-steps finished is skipped, exactly like the paper's c
     bitmap filter.
 
-Blocks are VMEM-resident (interpret-validated here; on a real TPU the
-edge window would stream HBM->VMEM via a scalar-prefetch index map — the
-grid/loop structure is unchanged).
+Edge tiles start at the 128-aligned row below the row tile's first
+edge (the compiler refuses unaligned 1-D dynamic slices); the per-row
+[start, end) mask drops the edges of neighbouring row tiles.  The edge
+window is one whole VMEM block (HBM streaming is future work).
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -32,71 +35,86 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.frontier import test_bits
+from repro.kernels import check_fast_memory
+
 INT_INF = 2**31 - 1  # python literal: pallas kernels must not capture arrays
+LANES = 128
+TILE_ROWS = 8                  # edge tile = (8, 128) = 1024 edges
+TILE_EDGES = TILE_ROWS * LANES
 
 
-def _kernel(meta_ref, rp_ref, ue_ref, fb_ref, c_ref, out_ref, *, rt: int,
-            et: int):
+def _kernel(bounds_ref, starts_ref, ends_ref, done_ref, val_ref, out_ref):
     r = pl.program_id(0)
-    row0 = r * rt
-    ptr = rp_ref[pl.ds(row0, rt + 1)]            # (rt+1,) window-rebased
-    tile_lo, tile_hi = ptr[0], ptr[rt]
-    col_off = meta_ref[0]
-    n_edges = meta_ref[1]
-    completed = c_ref[pl.ds(row0, rt)] != 0      # (rt,)
-    lanes = jnp.arange(rt, dtype=jnp.int32)
+    lo = bounds_ref[2 * r]                       # row tile's edge range
+    hi = bounds_ref[2 * r + 1]
+    starts = starts_ref[0]                       # (rt, 1) row [start, end)
+    ends = ends_ref[0]
+    done = done_ref[0] != 0                      # (rt, 1) completed rows
+    row0 = lo // LANES
+    lane = lax.broadcasted_iota(jnp.int32, (1, TILE_EDGES), 1)
 
+    # found = completed or discovered; derived, not carried (the loop
+    # carries int32 vectors only)
     def cond(state):
-        t, par, found = state
-        return (tile_lo + t * et < tile_hi) & jnp.logical_not(found.all())
+        t, par = state
+        open_rows = jnp.where(done | (par != INT_INF), 0, 1)
+        return (((row0 + t * TILE_ROWS) * LANES < hi)
+                & (jnp.max(open_rows) > 0))
 
     def body(state):
-        t, par, found = state
-        e0 = tile_lo + t * et
-        eidx = e0 + jnp.arange(et, dtype=jnp.int32)
-        ue = pl.load(ue_ref, (pl.ds(e0, et),))
-        valid = (eidx < tile_hi) & (eidx < n_edges)
-        # per-edge row via vectorized ptr compare (rows are sorted in CSR)
-        erow = jnp.sum((eidx[:, None] >= ptr[None, 1:]).astype(jnp.int32),
-                       axis=1)                                  # (et,)
-        w = fb_ref[ue >> 5]
-        in_f = ((w >> (ue.astype(jnp.uint32) & jnp.uint32(31))) & 1) == 1
-        live = jnp.logical_not(found)[jnp.clip(erow, 0, rt - 1)]
-        hit = valid & in_f & live
-        val = jnp.where(hit, col_off + ue, jnp.int32(INT_INF))
-        onehot = erow[:, None] == lanes[None, :]                # (et, rt)
-        tile_min = jnp.min(
-            jnp.where(onehot & hit[:, None], val[:, None],
-                      jnp.int32(INT_INF)), axis=0)
-        par = jnp.minimum(par, tile_min)
-        return t + 1, par, par != INT_INF
+        t, par = state
+        rs = row0 + t * TILE_ROWS
+        val = val_ref[pl.ds(rs, TILE_ROWS), :].reshape(1, TILE_EDGES)
+        e = rs * LANES + lane
+        mine = (e >= starts) & (e < ends) & jnp.logical_not(
+            done | (par != INT_INF))
+        par = jnp.minimum(par, jnp.min(jnp.where(mine, val, INT_INF),
+                                       axis=1, keepdims=True))
+        return t + 1, par
 
-    par0 = jnp.full((rt,), INT_INF, jnp.int32)
-    _, par, _ = lax.while_loop(cond, body, (jnp.int32(0), par0, completed))
-    out_ref[pl.ds(row0, rt)] = jnp.where(completed, INT_INF, par)
+    par0 = jnp.full(done.shape, INT_INF, jnp.int32)
+    _, par = lax.while_loop(cond, body, (jnp.int32(0), par0))
+    out_ref[0] = jnp.where(done, INT_INF, par)
 
 
 def bottomup_substep_kernel(rp_seg, ue_win, f_words, cvec, col_offset,
-                            n_edges, *, rt: int = 128, et: int = 512,
-                            interpret: bool = True):
+                            n_edges, *, rt: int = 128, interpret: bool):
     """(chunk+1,)(cap,)(ncw,)(chunk,) + scalars -> (chunk,) i32 parents."""
     chunk = rp_seg.shape[0] - 1
     rt = min(rt, chunk)
-    assert chunk % rt == 0, (chunk, rt)
-    meta = jnp.stack([jnp.asarray(col_offset, jnp.int32),
-                      jnp.asarray(n_edges, jnp.int32)])
-    grid = (chunk // rt,)
-    return pl.pallas_call(
-        functools.partial(_kernel, rt=rt, et=et),
-        grid=grid,
+    if chunk % rt:
+        raise ValueError(f"row tile {rt} does not divide chunk {chunk}")
+    nt = chunk // rt
+    cap = ue_win.shape[0]
+    # frontier-membership gather in XLA: each edge's candidate parent
+    eidx = jnp.arange(cap, dtype=jnp.int32)
+    hit = (eidx < n_edges) & test_bits(f_words, ue_win)
+    val = jnp.where(hit, jnp.asarray(col_offset, jnp.int32) + ue_win,
+                    jnp.int32(INT_INF)).astype(jnp.int32)
+    rows = -(-cap // LANES) + TILE_ROWS          # last tile may over-read
+    val = jnp.pad(val, (0, rows * LANES - cap),
+                  constant_values=INT_INF).reshape(rows, LANES)
+    # (rt, 1) column blocks pad to 128 lanes
+    check_fast_memory("bottom-up sub-step",
+                      vmem=4 * (val.size + 4 * rt * LANES), smem=8 * nt)
+    rp = rp_seg.astype(jnp.int32)
+    bounds = jnp.stack([rp[:-1:rt], rp[rt::rt]], axis=1).reshape(-1)
+    col = lambda x: x.reshape(nt, rt, 1)
+    tile = pl.BlockSpec((1, rt, 1), lambda r: (r, 0, 0))
+    out = pl.pallas_call(
+        _kernel,
+        grid=(nt,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),      # meta scalars
-            pl.BlockSpec(rp_seg.shape, lambda r: (0,)),  # row ptrs (VMEM)
-            pl.BlockSpec(ue_win.shape, lambda r: (0,)),  # edge window
-            pl.BlockSpec(f_words.shape, lambda r: (0,)),  # frontier bitmap
-            pl.BlockSpec(cvec.shape, lambda r: (0,)),    # completed
+            pl.BlockSpec(memory_space=pltpu.SMEM),   # row-tile edge bounds
+            tile,                                    # row starts
+            tile,                                    # row ends
+            tile,                                    # completed
+            pl.BlockSpec(val.shape, lambda r: (0, 0)),  # edge window
         ],
-        out_specs=pl.BlockSpec(cvec.shape, lambda r: (0,)),
-        out_shape=jax.ShapeDtypeStruct((chunk,), jnp.int32),
+        out_specs=tile,
+        out_shape=jax.ShapeDtypeStruct((nt, rt, 1), jnp.int32),
         interpret=interpret,
-    )(meta, rp_seg, ue_win, f_words, cvec.astype(jnp.int32))
+    )(bounds, col(rp[:-1]), col(rp[1:]), col((cvec != 0).astype(jnp.int32)),
+      val)
+    return out.reshape(chunk)

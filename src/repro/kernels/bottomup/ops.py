@@ -13,8 +13,8 @@ from repro.kernels.bottomup.ref import bottomup_substep as substep_ref
 __all__ = ["bottomup_substep", "substep_ref"]
 
 
-@functools.partial(jax.jit, static_argnames=("rt", "et", "interpret"))
+@functools.partial(jax.jit, static_argnames=("rt", "interpret"))
 def bottomup_substep(rp_seg, ue_win, f_words, cvec, col_offset, n_edges,
-                     rt: int = 128, et: int = 512, interpret: bool = True):
+                     rt: int = 128, *, interpret: bool):
     return bottomup_substep_kernel(rp_seg, ue_win, f_words, cvec, col_offset,
-                                   n_edges, rt=rt, et=et, interpret=interpret)
+                                   n_edges, rt=rt, interpret=interpret)

@@ -31,7 +31,7 @@ def _kernel(ids_ref, w_ref, table_ref, out_ref, *, bt: int, L: int,
 
 
 def embedding_bag_kernel(table, bag_ids, bag_weights=None, mode: str = "sum",
-                         bt: int = 128, interpret: bool = True):
+                         bt: int = 128, *, interpret: bool):
     B, L = bag_ids.shape
     V, D = table.shape
     bt = min(bt, B)

@@ -34,11 +34,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, bq: int, bk: int, sk: int,
 
     def body(j, state):
         m, l, acc = state
-        # index the leading block dim with a size-1 slice, not a literal
-        # int: jax 0.4.x's interpret-mode load discharge only accepts
-        # Slice/array indices.
-        kj = pl.load(k_ref, (pl.ds(0, 1), pl.ds(j * bk, bk), slice(None)))[0]
-        vj = pl.load(v_ref, (pl.ds(0, 1), pl.ds(j * bk, bk), slice(None)))[0]
+        kj = k_ref[0, pl.ds(j * bk, bk), :]
+        vj = v_ref[0, pl.ds(j * bk, bk), :]
         s = q @ kj.astype(jnp.float32).T               # (BQ, BK)
         kpos = j * bk + jnp.arange(bk, dtype=jnp.int32)
         mask = kpos[None, :] < sk
@@ -63,7 +60,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, bq: int, bk: int, sk: int,
 
 def flash_attention_kernel(q, k, v, *, causal: bool = True, window=None,
                            q_offset: int = 0, bq: int = 128, bk: int = 128,
-                           interpret: bool = True):
+                           interpret: bool):
     """q: (BH, Sq, dh); k, v: (BH, Sk, dh) -> (BH, Sq, dh)."""
     BH, Sq, dh = q.shape
     Sk = k.shape[1]

@@ -3,99 +3,137 @@
 Same math as the jnp oracle (ref.py) — count-prefixed fixed-width
 bit-packing of sorted local offsets — restructured for the VPU:
 
-  * Both directions are PER-BIT GATHERS with static shapes: packed bit b
-    is bit (b % bits) of offset b // bits.  No cross-word variable
-    shifts (every shift amount is < 32 by construction), no sequential
-    carry between words — each of the W output words is an independent
-    32-lane reduction, so encode vectorizes the way a delta-varint
-    stream never could.
+  * ``bits`` is static, so the packing is periodic: every
+    P = lcm(bits, 32) / bits offsets fill exactly Q = lcm(bits, 32) / 32
+    words.  Offsets are laid out as a (G, P) array (one period per row)
+    and words as (G, Q); slot k of a period lands at bit k*bits, i.e. in
+    word (k*bits) // 32 and, when it straddles a word boundary, the next
+    one.  Both directions are therefore a static schedule of column
+    slices and constant shifts — no gathers, which the TPU compiler
+    refuses inside a kernel, and no sequential carry between periods.
   * Encode runs as ONE program over the bucket (cap_x is small — the
     planned crossover capacity, not the chunk); decode runs a grid
     program per received bucket, rebasing offsets by the bucket's
     owner index k * chunk and emitting the ``unpack_ids`` drop
-    sentinel ``n`` for slots past the bucket's count word.
-
-Blocks are VMEM-resident with SMEM scalars, ``interpret=True`` by
-default (CPU CI), matching kernels/bottomup.
+    sentinel ``n`` for slots past the bucket's count word.  The count
+    word rides in SMEM; splitting it off the payload and the (G, Q)
+    reshapes happen in XLA around the kernels.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.comm_model import codec_bits, codec_packed_words
+from repro.kernels import check_fast_memory
 
 
-def _encode_kernel(count_ref, off_ref, out_ref, *, cap: int, bits: int,
-                   w: int):
-    count = jnp.minimum(count_ref[0].astype(jnp.uint32), jnp.uint32(cap))
-    slot = jnp.arange(cap, dtype=jnp.uint32)
-    v = jnp.where(slot < count, off_ref[...].astype(jnp.uint32),
-                  jnp.uint32(0))
-    b = jnp.arange(w * 32, dtype=jnp.uint32)
-    s = b // jnp.uint32(bits)
-    bit = (v[jnp.minimum(s, jnp.uint32(cap - 1))] >> (b % jnp.uint32(bits))
-           ) & jnp.uint32(1)
-    bit = jnp.where(s < cap, bit, jnp.uint32(0))
-    words = jnp.sum(bit.reshape(w, 32) << jnp.arange(32, dtype=jnp.uint32),
-                    axis=1, dtype=jnp.uint32)
-    out_ref[0] = count
-    out_ref[pl.ds(1, w)] = words
+def _period(bits: int):
+    """(P offsets, Q words) per period of the packed stream."""
+    lcm = bits * 32 // math.gcd(bits, 32)
+    return lcm // bits, lcm // 32
 
 
-def encode_offsets_kernel(off, count, chunk: int, *,
-                          interpret: bool = True):
+def _slot_words(k: int, bits: int):
+    """Slot k of a period starts at bit k*bits: (first word, bit offset
+    in it, second word or None when the slot fits in one word)."""
+    b0 = k * bits
+    q0, q1 = b0 // 32, (b0 + bits - 1) // 32
+    return q0, b0 - 32 * q0, (q1 if q1 != q0 else None)
+
+
+def _mask(bits: int):
+    return jnp.uint32((1 << bits) - 1)
+
+
+def _encode_kernel(count_ref, v_ref, out_ref, *, bits: int, p: int,
+                   q: int):
+    g = v_ref.shape[0]
+    slot = (lax.broadcasted_iota(jnp.int32, (g, p), 0) * p
+            + lax.broadcasted_iota(jnp.int32, (g, p), 1))
+    v = jnp.where(slot < count_ref[0], v_ref[...], jnp.uint32(0)) \
+        & _mask(bits)
+    words = [jnp.zeros((g, 1), jnp.uint32) for _ in range(q)]
+    for k in range(p):
+        q0, sh, q1 = _slot_words(k, bits)
+        col = v[:, k:k + 1]
+        words[q0] = words[q0] | (col << jnp.uint32(sh))
+        if q1 is not None:       # high bits spill into the next word
+            words[q1] = words[q1] | (col >> jnp.uint32(32 - sh))
+    out_ref[...] = jnp.concatenate(words, axis=1)
+
+
+def encode_offsets_kernel(off, count, chunk: int, *, interpret: bool):
     """(cap,) i32 local offsets + scalar live count -> (1+W,) uint32
     count-prefixed bit-packed bucket (W = ceil(cap*bits/32))."""
     cap = off.shape[0]
     bits = codec_bits(chunk)
     w = codec_packed_words(cap, bits)
-    count = jnp.asarray(count, jnp.int32).reshape(1)
-    return pl.pallas_call(
-        functools.partial(_encode_kernel, cap=cap, bits=bits, w=w),
+    p, q = _period(bits)
+    g = -(-cap // p)
+    count = jnp.minimum(jnp.asarray(count, jnp.int32), cap).reshape(1)
+    v = jnp.pad(off.astype(jnp.uint32), (0, g * p - cap)).reshape(g, p)
+    check_fast_memory("codec encode", vmem=4 * g * (p + q))
+    words = pl.pallas_call(
+        functools.partial(_encode_kernel, bits=bits, p=p, q=q),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),        # count scalar
-            pl.BlockSpec(off.shape, lambda: (0,)),        # offsets (VMEM)
+            pl.BlockSpec((g, p), lambda: (0, 0)),         # offsets (VMEM)
         ],
-        out_specs=pl.BlockSpec((1 + w,), lambda: (0,)),
-        out_shape=jax.ShapeDtypeStruct((1 + w,), jnp.uint32),
+        out_specs=pl.BlockSpec((g, q), lambda: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((g, q), jnp.uint32),
         interpret=interpret,
-    )(count, off)
+    )(count, v)
+    return jnp.concatenate([count.astype(jnp.uint32),
+                            words.reshape(-1)[:w]])
 
 
-def _decode_kernel(recv_ref, out_ref, *, cap: int, bits: int, w: int,
+def _decode_kernel(count_ref, w_ref, out_ref, *, bits: int, p: int,
                    chunk: int, n: int):
     k = pl.program_id(0)
-    base = k * (1 + w)
-    count = recv_ref[base].astype(jnp.int32)
-    packed = recv_ref[pl.ds(base + 1, w)]
-    b = jnp.arange(cap * bits, dtype=jnp.uint32)              # slot-major
-    bit = (packed[b >> jnp.uint32(5)] >> (b & jnp.uint32(31))
-           ) & jnp.uint32(1)
-    t = jnp.arange(bits, dtype=jnp.uint32)
-    val = jnp.sum(bit.reshape(cap, bits) << t[None, :],
-                  axis=1).astype(jnp.int32)
-    slot = jnp.arange(cap, dtype=jnp.int32)
-    out_ref[pl.ds(k * cap, cap)] = jnp.where(
-        slot < count, k * chunk + val, jnp.int32(n))
+    words = w_ref[0]                                      # (G, Q) u32
+    g = words.shape[0]
+    cols = []
+    for s in range(p):
+        q0, sh, q1 = _slot_words(s, bits)
+        x = words[:, q0:q0 + 1] >> jnp.uint32(sh)
+        if q1 is not None:
+            x = x | (words[:, q1:q1 + 1] << jnp.uint32(32 - sh))
+        cols.append(x)
+    val = jnp.concatenate(cols, axis=1) & _mask(bits)
+    slot = (lax.broadcasted_iota(jnp.int32, (g, p), 0) * p
+            + lax.broadcasted_iota(jnp.int32, (g, p), 1))
+    out_ref[0] = jnp.where(slot < count_ref[k],
+                           k * chunk + val.astype(jnp.int32), jnp.int32(n))
 
 
 def decode_buckets_kernel(recv, chunk: int, cap: int, n: int, p: int, *,
-                          interpret: bool = True):
+                          interpret: bool):
     """(p*(1+W),) uint32 allgathered buckets -> (p*cap,) i32 global ids
     (drop-sentinel ``n`` past each count), one grid program per bucket."""
     bits = codec_bits(chunk)
     w = codec_packed_words(cap, bits)
-    return pl.pallas_call(
-        functools.partial(_decode_kernel, cap=cap, bits=bits, w=w,
-                          chunk=chunk, n=n),
+    per, q = _period(bits)
+    g = -(-cap // per)
+    bufs = recv.reshape(p, 1 + w)
+    counts = bufs[:, 0].astype(jnp.int32)
+    words = jnp.pad(bufs[:, 1:], ((0, 0), (0, g * q - w))).reshape(p, g, q)
+    out = pl.pallas_call(
+        functools.partial(_decode_kernel, bits=bits, p=per, chunk=chunk,
+                          n=n),
         grid=(p,),
-        in_specs=[pl.BlockSpec(recv.shape, lambda k: (0,))],
-        out_specs=pl.BlockSpec((p * cap,), lambda k: (0,)),
-        out_shape=jax.ShapeDtypeStruct((p * cap,), jnp.int32),
+        in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),            # counts
+            pl.BlockSpec((1, g, q), lambda k: (k, 0, 0)),     # payloads
+        ],
+        out_specs=pl.BlockSpec((1, g, per), lambda k: (k, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((p, g, per), jnp.int32),
         interpret=interpret,
-    )(recv.reshape(-1))
+    )(counts, words)
+    return out.reshape(p, g * per)[:, :cap].reshape(-1)

@@ -18,13 +18,13 @@ __all__ = ["encode_offsets", "decode_buckets",
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def encode_offsets(off, count, chunk: int, interpret: bool = True):
+def encode_offsets(off, count, chunk: int, *, interpret: bool):
     return encode_offsets_kernel(off, count, chunk, interpret=interpret)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("chunk", "cap", "n", "p", "interpret"))
-def decode_buckets(recv, chunk: int, cap: int, n: int, p: int,
-                   interpret: bool = True):
+def decode_buckets(recv, chunk: int, cap: int, n: int, p: int, *,
+                   interpret: bool):
     return decode_buckets_kernel(recv, chunk, cap, n, p,
                                  interpret=interpret)

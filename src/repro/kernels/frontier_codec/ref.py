@@ -14,9 +14,10 @@ allgather identifies the owner.  The encoding is:
                       packed as 0 and ignored by the decoder
 
 ``bits`` is static (chunk is a partition constant), so encode and decode
-are pure vectorized gathers: packed bit b is bit (b % bits) of offset
-b // bits — no variable-length scan, unlike a delta-varint stream whose
-decode is inherently sequential.  Compression is 32/bits (~3x at
+are pure vectorized reshapes: packed bit b is bit (b % bits) of offset
+b // bits, i.e. the (cap, bits) bit matrix of the offsets read in
+row-major order — no variable-length scan, unlike a delta-varint stream
+whose decode is inherently sequential.  Compression is 32/bits (~3x at
 chunk=1024) on the physical buffer and 64/bits on the modeled id words
 (``comm_model.compressed_expand_1d_words``).
 
@@ -44,13 +45,10 @@ def encode_offsets(off: jax.Array, count: jax.Array, chunk: int
     count = jnp.minimum(jnp.asarray(count, jnp.uint32), jnp.uint32(cap))
     slot = jnp.arange(cap, dtype=jnp.uint32)
     v = jnp.where(slot < count, off.astype(jnp.uint32), jnp.uint32(0))
-    # packed bit b = bit (b % bits) of offset b // bits — one gather,
-    # no cross-word shift hazards
-    b = jnp.arange(w * 32, dtype=jnp.uint32)
-    s = b // jnp.uint32(bits)
-    bit = (v[jnp.minimum(s, jnp.uint32(cap - 1))] >> (b % jnp.uint32(bits))
-           ) & jnp.uint32(1)
-    bit = jnp.where(s < cap, bit, jnp.uint32(0))
+    # packed bit b = bit (b % bits) of offset b // bits: the row-major
+    # (cap, bits) bit matrix, zero-padded to whole words
+    bit = (v[:, None] >> jnp.arange(bits, dtype=jnp.uint32)) & jnp.uint32(1)
+    bit = jnp.pad(bit.reshape(-1), (0, w * 32 - cap * bits))
     words = jnp.sum(bit.reshape(w, 32) << jnp.arange(32, dtype=jnp.uint32),
                     axis=1, dtype=jnp.uint32)
     return jnp.concatenate([count.reshape(1), words])
@@ -70,9 +68,10 @@ def decode_buckets(recv: jax.Array, chunk: int, cap: int, n: int
     packed = bufs[:, 1:]                                      # (p, W)
     slot = jnp.arange(cap, dtype=jnp.uint32)
     t = jnp.arange(bits, dtype=jnp.uint32)
-    b = slot[:, None] * jnp.uint32(bits) + t[None, :]         # (cap, bits)
-    word = packed[:, b >> jnp.uint32(5)]                      # (p, cap, bits)
-    bit = (word >> (b & jnp.uint32(31))[None]) & jnp.uint32(1)
+    # the packed stream bit by bit, read back as (cap, bits) rows
+    stream = (packed[:, :, None] >> jnp.arange(32, dtype=jnp.uint32)
+              ) & jnp.uint32(1)                               # (p, W, 32)
+    bit = stream.reshape(p, w * 32)[:, : cap * bits].reshape(p, cap, bits)
     val = jnp.sum(bit << t[None, None, :], axis=-1).astype(jnp.int32)
     k = jnp.arange(p, dtype=jnp.int32)[:, None]
     ids = jnp.where(slot[None, :].astype(jnp.int32) < counts[:, None],

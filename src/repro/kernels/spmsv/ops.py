@@ -11,6 +11,7 @@ non-empty global columns against the allgathered frontier bitmap
 from __future__ import annotations
 
 import jax.numpy as jnp
+from jax import lax
 
 from repro.core.frontier import INT_INF
 from repro.kernels.spmsv.spmsv import gather_segments
@@ -18,8 +19,13 @@ from repro.kernels.spmsv.strip import (gather_strip_segments,
                                        gather_strip_segments_chunk)
 
 
-def _scatter_min(dst, ids, col_offset, nr, cap_f):
-    """(cap_f, maxdeg) gathered dest rows + frontier ids -> candidates."""
+def _scatter_min(dst, ids, col_offset, nr):
+    """(slots, width) gathered dest rows (-1 = none) + the slots' source
+    ids -> (nr,) candidate parents."""
+    # the barrier keeps the TPU compiler from fusing the kernel output's
+    # relayout into the scatter: fused, a scale-14 search took ~55 s to
+    # compile for v5e instead of ~1 s
+    dst = lax.optimization_barrier(dst)
     parent = (col_offset + ids).astype(jnp.int32)[:, None]
     valid = dst >= 0
     vals = jnp.where(valid, jnp.broadcast_to(parent, dst.shape), INT_INF)
@@ -34,7 +40,7 @@ def frontier_ids(f_cj: jnp.ndarray, cap_f: int, nc: int):
 
 
 def spmsv_block_csr(col_ptr, row_idx, f_cj, nr: int, col_offset,
-                    *, cap_f: int, maxdeg: int, interpret: bool = True):
+                    *, cap_f: int, maxdeg: int, interpret: bool):
     nc = f_cj.shape[0]
     ids, live = frontier_ids(f_cj, cap_f, nc)
     idc = jnp.minimum(ids, nc - 1)
@@ -42,11 +48,11 @@ def spmsv_block_csr(col_ptr, row_idx, f_cj, nr: int, col_offset,
     lens = jnp.where(live, col_ptr[idc + 1] - starts, 0)
     dst = gather_segments(starts, lens, row_idx, cap_f=cap_f,
                           maxdeg=maxdeg, interpret=interpret)
-    return _scatter_min(dst, ids, col_offset, nr, cap_f)
+    return _scatter_min(dst, ids, col_offset, nr)
 
 
 def spmsv_strip_dcsc(jc, cp, nzc, row_idx, f_words, nr: int,
-                     *, maxdeg: int, interpret: bool = True):
+                     *, maxdeg: int, interpret: bool):
     """1D strip SpMSV over doubly compressed global source columns: the
     kernel walks the nzc slots, bitmap-testing each column against the
     allgathered frontier, so there is no per-frontier-vertex lookup and
@@ -56,12 +62,12 @@ def spmsv_strip_dcsc(jc, cp, nzc, row_idx, f_words, nr: int,
                                 maxdeg=maxdeg, interpret=interpret)
     # sentinel slots (jc = n) gather nothing, so their parent value is
     # never scattered; col_offset=0 keeps the ids global
-    return _scatter_min(dst, jc, jnp.int32(0), nr, jc.shape[0])
+    return _scatter_min(dst, jc, jnp.int32(0), nr)
 
 
 def spmsv_strip_dcsc_chunk(jc, cp, nzc, row_idx, f_sub, nr: int, *, n: int,
                            p: int, k: int, n_chunks: int, maxdeg: int,
-                           interpret: bool = True):
+                           interpret: bool):
     """Software-pipelined strip SpMSV step: consume ONE gathered
     sub-chunk of the chunked expand (owner-major ``(p * w_sub,)`` u32
     words covering owner-local word range [k*w_sub, (k+1)*w_sub)) with
@@ -71,11 +77,11 @@ def spmsv_strip_dcsc_chunk(jc, cp, nzc, row_idx, f_sub, nr: int, *, n: int,
     dst = gather_strip_segments_chunk(jc, cp, nzc, row_idx, f_sub, n=n, p=p,
                                       k=k, n_chunks=n_chunks, maxdeg=maxdeg,
                                       interpret=interpret)
-    return _scatter_min(dst, jc, jnp.int32(0), nr, jc.shape[0])
+    return _scatter_min(dst, jc, jnp.int32(0), nr)
 
 
 def spmsv_block_dcsc(jc, cp, nzc, row_idx, f_cj, nr: int, col_offset,
-                     *, cap_f: int, maxdeg: int, interpret: bool = True):
+                     *, cap_f: int, maxdeg: int, interpret: bool):
     nc = f_cj.shape[0]
     ids, live = frontier_ids(f_cj, cap_f, nc)
     # binary search in the compressed column ids (the DCSC indirection)
@@ -86,4 +92,4 @@ def spmsv_block_dcsc(jc, cp, nzc, row_idx, f_cj, nr: int, col_offset,
     lens = jnp.where(found, cp[pos + 1] - cp[pos], 0)
     dst = gather_segments(starts, lens, row_idx, cap_f=cap_f,
                           maxdeg=maxdeg, interpret=interpret)
-    return _scatter_min(dst, ids, col_offset, nr, cap_f)
+    return _scatter_min(dst, ids, col_offset, nr)

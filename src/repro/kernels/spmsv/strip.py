@@ -1,4 +1,4 @@
-"""Pallas TPU kernel: strip SpMSV for the 1D row decomposition.
+"""Strip SpMSV gather for the 1D row decomposition.
 
 A 1D strip T[V_i, :] spans *every* global source column, so an
 uncompressed CSC col_ptr costs n+1 words per processor — the O(n)
@@ -7,148 +7,86 @@ storage, and the reason the 1D path was dense-only until now.  Strip
 DCSC stores just the strip's non-empty global columns (``jc``) with
 pointers (``cp``) into the CSC-ordered ``row_idx``, O(nzc) words.
 
-The kernel walks ``jc`` — NOT the frontier — because nzc <= nnz is the
-strip-local quantity while the frontier is global: for each non-empty
-column slot it tests the column id against the allgathered frontier
-*bitmap* (packed uint32 words, the same representation the 1D expand
-allgathers), and gathers that column's contiguous segment in ET-wide
-tiles, reusing the ragged-gather tiling of the 2D kernel (spmsv.py).
-Skipped tiles (column not in frontier / beyond the segment) cost only
-control overhead, so traffic ~ sum of frontier-column degrees.
+The gather walks ``jc`` — NOT the frontier — because nzc <= nnz is the
+strip-local quantity while the frontier is global: each non-empty
+column slot is tested against the allgathered frontier *bitmap* (packed
+uint32 words, the same representation the 1D expand allgathers), and
+the live columns' contiguous segments are gathered by the ragged-gather
+kernel of the 2D path (spmsv.py).  Dead slots get zero-length segments,
+so traffic ~ sum of frontier-column degrees.
 
-As in the 2D split, the SPA accumulation (scatter-min of global source
-ids, the paper's §5.2 sparse accumulator) stays outside the kernel where
-XLA lowers it to a sorted segment reduction.
+The bitmap test is a data-dependent 1-D gather, which the TPU compiler
+refuses inside a kernel, so it runs in XLA around the ``pallas_call``
+and hands the kernel plain (start, length) segments.  As in the 2D
+split, the SPA accumulation (scatter-min of global source ids, the
+paper's §5.2 sparse accumulator) stays outside the kernel where XLA
+lowers it to a sorted segment reduction.
 """
 from __future__ import annotations
 
-import functools
-
-import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from repro.core.frontier import test_bits
+from repro.kernels.spmsv.spmsv import gather_segments
 
 
-def _strip_gather_kernel(jc_ref, cp_ref, nzc_ref, fw_ref, ridx_ref, out_ref,
-                         *, et: int, n: int):
-    g = pl.program_id(0)          # non-empty-column slot
-    t = pl.program_id(1)          # edge tile within the slot's segment
-    u = jc_ref[g]                 # GLOBAL source column id (sentinel = n)
-    uc = jnp.minimum(u, n - 1)
-    w = fw_ref[uc >> 5]           # frontier bitmap word (uint32)
-    in_f = ((w >> (uc.astype(jnp.uint32) & jnp.uint32(31))) & 1) == 1
-    live = (g < nzc_ref[0]) & (u < n) & in_f
-    s = cp_ref[g]
-    ln = jnp.where(live, cp_ref[g + 1] - s, 0)
-    off = t * et
-
-    @pl.when(off < ln)
-    def _():
-        lane = jnp.arange(et, dtype=jnp.int32)
-        v = pl.load(ridx_ref, (pl.ds(s + off, et),))
-        out_ref[0, :] = jnp.where(off + lane < ln, v, jnp.int32(-1))
-
-    @pl.when(off >= ln)
-    def _():
-        out_ref[0, :] = jnp.full((et,), -1, jnp.int32)
-
-
-def gather_strip_segments(jc, cp, nzc, row_idx, f_words, *, maxdeg: int,
-                          et: int = 256, interpret: bool = True):
-    """(cap_nzc,) DCSC columns -> (cap_nzc, maxdeg) gathered dest rows of
-    the columns present in the frontier bitmap, -1 padded.  row_idx must
-    be padded by >= et beyond the last segment."""
+def strip_live_columns(jc, nzc, f_words):
+    """(cap_nzc,) bool: DCSC slot holds a real column present in the
+    frontier bitmap ``f_words`` (sentinel columns are ``jc == n``)."""
     n = f_words.shape[0] * 32
-    cap_nzc = jc.shape[0]
-    maxdeg = ((max(maxdeg, 1) + et - 1) // et) * et
-    grid = (cap_nzc, maxdeg // et)
-    return pl.pallas_call(
-        functools.partial(_strip_gather_kernel, et=et, n=n),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),            # jc
-            pl.BlockSpec(memory_space=pltpu.SMEM),            # cp
-            pl.BlockSpec(memory_space=pltpu.SMEM),            # nzc (1,)
-            pl.BlockSpec(memory_space=pltpu.SMEM),            # frontier words
-            pl.BlockSpec(row_idx.shape, lambda g, t: (0,)),   # edge ids (VMEM)
-        ],
-        out_specs=pl.BlockSpec((1, et), lambda g, t: (g, t)),
-        out_shape=jax.ShapeDtypeStruct((cap_nzc, maxdeg), jnp.int32),
-        interpret=interpret,
-    )(jc.astype(jnp.int32), cp.astype(jnp.int32),
-      jnp.asarray(nzc, jnp.int32).reshape(1), f_words, row_idx)
+    slot = jnp.arange(jc.shape[0])
+    return (slot < nzc) & (jc < n) & test_bits(f_words,
+                                               jnp.minimum(jc, n - 1))
 
 
-def _strip_gather_chunk_kernel(jc_ref, cp_ref, nzc_ref, fw_ref, ridx_ref,
-                               out_ref, *, et: int, n: int, wpc: int,
-                               w_sub: int, k: int):
-    """Per-chunk entry of the strip gather: ``fw_ref`` is the RAW gathered
-    sub-chunk buffer of one software-pipelined expand step — owner-major
+def strip_live_columns_chunk(jc, nzc, f_sub, *, n: int, p: int, k: int,
+                             n_chunks: int):
+    """Per-chunk liveness for the software-pipelined expand: ``f_sub`` is
+    the RAW gathered sub-chunk buffer of pipeline step ``k`` — owner-major
     ``(p * w_sub,)`` u32 words covering owner-local word range
     [k*w_sub, (k+1)*w_sub) of each owner's ``wpc``-word strip — consumed
     directly, so no full-size frontier bitmap is ever materialized.  A
-    column is live only when it falls inside sub-chunk k; the caller
-    min-combines the per-chunk scatter results (exact under the
-    (select-source, min) semiring)."""
-    g = pl.program_id(0)          # non-empty-column slot
-    t = pl.program_id(1)          # edge tile within the slot's segment
-    u = jc_ref[g]                 # GLOBAL source column id (sentinel = n)
-    uc = jnp.minimum(u, n - 1)
-    wi = uc >> 5                  # global packed-word index
-    owner = wi // wpc
-    lw = wi - owner * wpc         # word index within the owner's strip
-    in_rng = (lw >= k * w_sub) & (lw < (k + 1) * w_sub)
-    pos = jnp.where(in_rng, owner * w_sub + (lw - k * w_sub), 0)
-    w = fw_ref[pos]
-    in_f = ((w >> (uc.astype(jnp.uint32) & jnp.uint32(31))) & 1) == 1
-    live = (g < nzc_ref[0]) & (u < n) & in_rng & in_f
-    s = cp_ref[g]
-    ln = jnp.where(live, cp_ref[g + 1] - s, 0)
-    off = t * et
-
-    @pl.when(off < ln)
-    def _():
-        lane = jnp.arange(et, dtype=jnp.int32)
-        v = pl.load(ridx_ref, (pl.ds(s + off, et),))
-        out_ref[0, :] = jnp.where(off + lane < ln, v, jnp.int32(-1))
-
-    @pl.when(off >= ln)
-    def _():
-        out_ref[0, :] = jnp.full((et,), -1, jnp.int32)
-
-
-def gather_strip_segments_chunk(jc, cp, nzc, row_idx, f_sub, *, n: int,
-                                p: int, k: int, n_chunks: int, maxdeg: int,
-                                et: int = 256, interpret: bool = True):
-    """Chunked variant of ``gather_strip_segments``: ``f_sub`` is the
-    owner-major gathered sub-chunk words ``(p * w_sub,)`` of pipeline
-    step ``k`` (of ``n_chunks``).  ``n`` and ``p`` are passed explicitly
-    — the buffer no longer spans the full vertex range, so neither is
-    derivable from its shape (every sub-chunk buffer is exactly
-    (n/32)/n_chunks words regardless of p)."""
+    column is live only when it falls inside sub-chunk k.  ``n`` and ``p``
+    are explicit: the buffer no longer spans the full vertex range."""
     wpc = (n // p) // 32                  # packed words per owner strip
     w_sub = wpc // n_chunks
     if f_sub.shape[0] != p * w_sub:
         raise ValueError(
             f"sub-chunk buffer has {f_sub.shape[0]} words, expected "
             f"p*w_sub = {p}*{w_sub} for n={n}, n_chunks={n_chunks}")
-    cap_nzc = jc.shape[0]
-    maxdeg = ((max(maxdeg, 1) + et - 1) // et) * et
-    grid = (cap_nzc, maxdeg // et)
-    return pl.pallas_call(
-        functools.partial(_strip_gather_chunk_kernel, et=et, n=n, wpc=wpc,
-                          w_sub=w_sub, k=k),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),            # jc
-            pl.BlockSpec(memory_space=pltpu.SMEM),            # cp
-            pl.BlockSpec(memory_space=pltpu.SMEM),            # nzc (1,)
-            pl.BlockSpec(memory_space=pltpu.SMEM),            # sub-chunk words
-            pl.BlockSpec(row_idx.shape, lambda g, t: (0,)),   # edge ids (VMEM)
-        ],
-        out_specs=pl.BlockSpec((1, et), lambda g, t: (g, t)),
-        out_shape=jax.ShapeDtypeStruct((cap_nzc, maxdeg), jnp.int32),
-        interpret=interpret,
-    )(jc.astype(jnp.int32), cp.astype(jnp.int32),
-      jnp.asarray(nzc, jnp.int32).reshape(1), f_sub, row_idx)
+    slot = jnp.arange(jc.shape[0])
+    uc = jnp.minimum(jc, n - 1)
+    wi = uc >> 5                          # global packed-word index
+    owner = wi // wpc
+    lw = wi - owner * wpc                 # word index within the owner's strip
+    in_rng = (lw >= k * w_sub) & (lw < (k + 1) * w_sub)
+    pos = jnp.where(in_rng, owner * w_sub + (lw - k * w_sub), 0)
+    bit = ((f_sub[pos] >> (uc.astype(jnp.uint32) & jnp.uint32(31)))
+           & jnp.uint32(1)) == 1
+    return (slot < nzc) & (jc < n) & in_rng & bit
+
+
+def _gather_live(cp, live, row_idx, maxdeg: int, interpret: bool):
+    lens = jnp.where(live, cp[1:] - cp[:-1], 0)
+    return gather_segments(cp[:-1], lens, row_idx, cap_f=live.shape[0],
+                           maxdeg=maxdeg, interpret=interpret)
+
+
+def gather_strip_segments(jc, cp, nzc, row_idx, f_words, *, maxdeg: int,
+                          interpret: bool):
+    """(cap_nzc,) DCSC columns -> ``gather_segments`` rows of the
+    columns present in the frontier bitmap, -1 elsewhere."""
+    return _gather_live(cp, strip_live_columns(jc, nzc, f_words), row_idx,
+                        maxdeg, interpret)
+
+
+def gather_strip_segments_chunk(jc, cp, nzc, row_idx, f_sub, *, n: int,
+                                p: int, k: int, n_chunks: int, maxdeg: int,
+                                interpret: bool):
+    """Chunked variant of ``gather_strip_segments`` over one pipelined
+    sub-chunk buffer (see ``strip_live_columns_chunk``); the caller
+    min-combines the per-chunk scatter results (exact under the
+    (select-source, min) semiring)."""
+    live = strip_live_columns_chunk(jc, nzc, f_sub, n=n, p=p, k=k,
+                                    n_chunks=n_chunks)
+    return _gather_live(cp, live, row_idx, maxdeg, interpret)

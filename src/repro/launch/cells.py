@@ -25,7 +25,6 @@ from repro.configs.base import (BFSConfig, BFSShape, GNNConfig, GNNShape,
                                 LMConfig, LMShape, RecsysConfig, RecsysShape,
                                 get_config)
 from repro.core import steps as bfs_steps
-from repro.core.compat import shard_map
 from repro.core.engine import plan_for_part
 from repro.core.local_ops import get_local_ops
 from repro.core.partition import make_partition
@@ -425,7 +424,7 @@ def build_bfs_cell(cfg: BFSConfig, shape: BFSShape, mesh,
             return pi2[None, None], f2[None, None]
 
         spec = P("data", "model")
-        mapped = shard_map(
+        mapped = jax.shard_map(
             level_fn, mesh=mesh,
             in_specs=({k: spec for k in keys}, spec, spec),
             out_specs=(spec, spec), check_vma=False)

@@ -3,8 +3,6 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 # ^ MUST be the first two lines, before ANY other import: jax locks the
 # device count on first init.  The dry-run (and only the dry-run) builds
 # the production 16x16 / 2x16x16 meshes out of 512 host devices.
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/jax_dryrun_cache")
-os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "1")
 
 """Multi-pod dry-run: .lower().compile() every (architecture x input
 shape x mesh) cell on the production mesh, record memory/cost analysis +
@@ -26,6 +24,7 @@ import traceback
 import jax
 
 from repro.launch import cells as cells_mod
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.launch.roofline import collective_bytes_from_hlo, roofline_report
 
@@ -82,6 +81,7 @@ def main(argv=None):
                     choices=["single", "multi", "both"])
     ap.add_argument("--force", action="store_true")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     if args.cells == "all":
         todo = cells_mod.all_cells() + cells_mod.bfs_cells()

@@ -15,7 +15,6 @@ from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import get_config
-from repro.core.compat import shard_map
 from repro.core.partition import make_partition
 from repro.launch.cells import Cell, _ns, _round_up, _sds
 from repro.optim.adamw import AdamW, AdamWState
@@ -91,7 +90,7 @@ def build_mace2d_cell(shape_name: str, mesh) -> Cell:
     opt = AdamW()
     opt_state = jax.eval_shape(opt.init, params)
     opt_sh = AdamWState(step=_ns(mesh), mu=p_sh, nu=p_sh)
-    mapped = shard_map(
+    mapped = jax.shard_map(
         loss_body, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(), params), spec, spec, spec,
                   spec, spec, P()),
@@ -162,7 +161,7 @@ def build_gin2d_cell(shape_name: str, mesh) -> Cell:
     opt_state = jax.eval_shape(opt.init, params)
     opt_sh = AdamWState(step=_ns(mesh), mu=p_sh, nu=p_sh)
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         loss_body, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(), params), spec, spec, spec,
                   spec, spec, spec),

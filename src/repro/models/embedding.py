@@ -23,7 +23,6 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import RecsysConfig
-from repro.core.compat import shard_map
 from repro.models.common import ShardCtx
 
 
@@ -67,7 +66,7 @@ def lookup(table: jnp.ndarray, rows: jnp.ndarray, ctx: ShardCtx):
     flat = rows.reshape(-1)
     dp_total = int(np.prod([ctx.mesh.shape[a] for a in dpa])) if dpa else 1
     rspec = P(dpa) if (dpa and flat.shape[0] % dp_total == 0) else P(None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=ctx.mesh,
         in_specs=(P("model", None), rspec),
         out_specs=P(*rspec, None), check_vma=False,
@@ -82,7 +81,8 @@ def embedding_bag(table, bag_ids, bag_weights=None, mode: str = "sum",
     (interpret-validated; single-device only)."""
     if use_kernel and (ctx.mesh is None or ctx.tp_size == 1):
         from repro.kernels.embedding_bag import ops as eb_ops
-        return eb_ops.embedding_bag(table, bag_ids, bag_weights, mode=mode)
+        return eb_ops.embedding_bag(table, bag_ids, bag_weights, mode=mode,
+                                    interpret=jax.default_backend() == "cpu")
     valid = bag_ids >= 0
     safe = jnp.where(valid, bag_ids, 0)
     vals = lookup(table, safe, ctx)

@@ -26,7 +26,6 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import LMConfig
-from repro.core.compat import shard_map
 from repro.models.common import ShardCtx, chunked_attention, rms_norm, rope
 
 # ---------------------------------------------------------------------------
@@ -213,7 +212,7 @@ def moe_ep_shardmap(x, router_w, wg, wu, wd, cfg: LMConfig, ctx: ShardCtx,
             Eg * tp_sub, D, Fs)
         wd = wd.reshape(Eg, tp_sub, Fs, D).reshape(Eg * tp_sub, Fs, D)
     wspec = P("model", None, None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=ctx.mesh,
         in_specs=(tok_spec, P(None, None), wspec, wspec, wspec),
         out_specs=tok_spec, check_vma=False,
@@ -266,7 +265,7 @@ def moe_decode_psum(x, router_w, wg, wu, wd, cfg: LMConfig, ctx: ShardCtx):
         return lax.psum(out, "model").astype(xl.dtype)
 
     dpa = ctx.dp
-    return shard_map(
+    return jax.shard_map(
         body, mesh=ctx.mesh,
         in_specs=(P(dpa if dpa else None, None), P(None, None),
                   P("model", None, None), P("model", None, None),

@@ -19,7 +19,6 @@ import jax
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.core.compat import shard_map
 from repro.optim.grad_compress import (ef_init, int8_dequantize,
                                        int8_quantize, topk_compress,
                                        topk_decompress)
@@ -49,7 +48,7 @@ def make_dp_compressed_step(loss_fn: Callable, opt, mesh, dp_axis: str,
         return (new_p, new_o, ef), {"loss": loss}
 
     def step(state, batch):
-        mapped = shard_map(
+        mapped = jax.shard_map(
             body, mesh=mesh,
             in_specs=((jax.tree.map(lambda _: P(), state[0]),
                        jax.tree.map(lambda _: P(), state[1]),

@@ -25,7 +25,6 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
-from jax.experimental.shard_map import shard_map  # noqa: E402
 from jax.sharding import Mesh, PartitionSpec as P  # noqa: E402
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
@@ -42,8 +41,8 @@ def build():
                              [(i, (i + 1) % 8) for i in range(8)])
         return s + g.sum(axis=0, keepdims=True) + t + r
 
-    fn = jax.jit(shard_map(local, mesh=mesh, in_specs=P("x", None),
-                           out_specs=P("x", None)))
+    fn = jax.jit(jax.shard_map(local, mesh=mesh, in_specs=P("x", None),
+                               out_specs=P("x", None)))
     sds = jax.ShapeDtypeStruct((8, 8), jnp.float32)
     return fn.lower(sds)
 

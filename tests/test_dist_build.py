@@ -20,7 +20,7 @@ def test_counter_twins_bit_identical():
     ju, jv = rmat_edges_counter_jax(SCALE, count, 0, edge_factor=EF,
                                     seed=SEED)
     ku, kv = rmat_edges_counter_kernel(SCALE, count, 0, edge_factor=EF,
-                                       seed=SEED)
+                                       seed=SEED, interpret=True)
     assert np.array_equal(su, np.asarray(ju))
     assert np.array_equal(sv, np.asarray(jv))
     assert np.array_equal(su, np.asarray(ku))
@@ -35,7 +35,7 @@ def test_counter_offset_slices():
     assert np.array_equal(u, full_u[777:1110])
     assert np.array_equal(v, full_v[777:1110])
     ku, kv = rmat_edges_counter_kernel(SCALE, 333, 777, edge_factor=EF,
-                                       seed=SEED)
+                                       seed=SEED, interpret=True)
     assert np.array_equal(np.asarray(ku), full_u[777:1110])
     assert np.array_equal(np.asarray(kv), full_v[777:1110])
 

@@ -52,6 +52,18 @@ def test_decomp_registry():
             else make_partition_1d(64, 1, align=32)))
 
 
+@pytest.mark.parametrize("d", ["1d", "1ds", "2d"])
+def test_plan_on_cpu_mesh_interprets_kernels(fixed_graph, d):
+    """A plan reads the interpret switch off its mesh: on a CPU mesh
+    the Pallas kernels run in the interpreter, and the flag reaches the
+    level steps (tests/test_tpu_compile.py pins the TPU side)."""
+    e, g1, g2 = fixed_graph
+    plan = plan_bfs(_graph_for(d, g1, g2),
+                    BFSConfig(decomposition=d, storage="dcsc"),
+                    _mesh_for(d), local_mode="kernel")
+    assert plan.statics.interpret and plan.level_args().interpret
+
+
 def test_unknown_decomposition_rejected_at_plan(fixed_graph):
     e, g1, g2 = fixed_graph
     with pytest.raises(ValueError, match="no decomposition registered"):

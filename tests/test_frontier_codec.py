@@ -18,11 +18,11 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
+import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.core import comm_model
-from repro.core.compat import shard_map
 from repro.core.frontier import pack_ids, unpack_bits, unpack_ids
 from repro.core.steps_1d_sparse import sparse_exchange_1d
 from repro.graph.formats import build_blocked_1d
@@ -118,14 +118,15 @@ def test_codec_roundtrip_property(seed):
         e_ref = codec_ref.encode_offsets(jnp.asarray(offp), jnp.int32(cnt),
                                          chunk)
         e_ker = codec_ops.encode_offsets(jnp.asarray(offp), jnp.int32(cnt),
-                                         chunk)
+                                         chunk, interpret=True)
         assert np.array_equal(np.asarray(e_ref), np.asarray(e_ker))
         assert int(np.asarray(e_ref)[0]) == cnt     # count word is first
         bufs.append(np.asarray(e_ref))
         want.append(k * chunk + off)
     recv = jnp.asarray(np.concatenate(bufs))
     d_ref = np.asarray(codec_ref.decode_buckets(recv, chunk, cap, n))
-    d_ker = np.asarray(codec_ops.decode_buckets(recv, chunk, cap, n, p))
+    d_ker = np.asarray(codec_ops.decode_buckets(recv, chunk, cap, n, p,
+                                                interpret=True))
     assert np.array_equal(d_ref, d_ker)
     live = d_ref[d_ref < n]
     assert np.array_equal(np.sort(live), np.sort(np.concatenate(want)))
@@ -165,11 +166,11 @@ def _exchange(front, part, cap_x, visited=None, codec="none",
         f_words, wire, over = sparse_exchange_1d(
             f[0], "data", cap_x, part, instrument=True,
             visited=None if visited is None else v[0],
-            codec=codec, use_kernel=use_kernel)
+            codec=codec, use_kernel=use_kernel, interpret=True)
         return f_words[None], over.reshape(1)
 
     v_in = np.zeros_like(front) if visited is None else visited
-    words, over = shard_map(
+    words, over = jax.shard_map(
         body, mesh=mesh, in_specs=(P("data"), P("data")),
         out_specs=(P("data"), P("data")), check_vma=False)(front, v_in)
     return (np.asarray(unpack_bits(jnp.asarray(words[0]))),

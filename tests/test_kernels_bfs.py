@@ -45,7 +45,8 @@ def test_spmsv_kernel_matches_dense(nc, nr, density, fdensity):
     maxdeg = max(int(np.diff(col_ptr).max()), 1)
     ridx = jnp.pad(jnp.asarray(row_idx), (0, 256))
     got = spmsv_ops.spmsv_block_csr(jnp.asarray(col_ptr), ridx, f_cj, nr,
-                                    col_offset, cap_f=nc, maxdeg=maxdeg)
+                                    col_offset, cap_f=nc, maxdeg=maxdeg,
+                                    interpret=True)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     # DCSC path: build compressed pointers and require identical output
     deg = np.diff(col_ptr)
@@ -57,7 +58,7 @@ def test_spmsv_kernel_matches_dense(nc, nr, density, fdensity):
     cp[len(nzcols):] = nnz
     got2 = spmsv_ops.spmsv_block_dcsc(
         jnp.asarray(jc), jnp.asarray(cp), jnp.int32(len(nzcols)), ridx,
-        f_cj, nr, col_offset, cap_f=nc, maxdeg=maxdeg)
+        f_cj, nr, col_offset, cap_f=nc, maxdeg=maxdeg, interpret=True)
     np.testing.assert_array_equal(np.asarray(got2), np.asarray(want))
 
 
@@ -86,7 +87,8 @@ def test_spmsv_strip_kernel_matches_dense(chunk, n, fdensity):
     maxdeg = int(np.diff(np.append(first, m)).max())
     got = spmsv_ops.spmsv_strip_dcsc(
         jnp.asarray(jc), jnp.asarray(cp), jnp.int32(nzc),
-        jnp.pad(jnp.asarray(v), (0, 256)), f_words, chunk, maxdeg=maxdeg)
+        jnp.pad(jnp.asarray(v), (0, 256)), f_words, chunk, maxdeg=maxdeg,
+        interpret=True)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
@@ -112,7 +114,7 @@ def test_bottomup_kernel_matches_ref(chunk, nc, fdensity, cdensity):
                   jnp.asarray(cvec), col_offset, ne)
     got = bu_kernel(jnp.asarray(rp), jnp.pad(jnp.asarray(ue), (0, 512)),
                     f_words, jnp.asarray(cvec), col_offset, ne,
-                    rt=min(128, chunk))
+                    rt=min(128, chunk), interpret=True)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
@@ -135,7 +137,8 @@ def test_bottomup_kernel_property(seed):
     args = (jnp.asarray(rp), jnp.asarray(ue), f_words, jnp.asarray(cvec),
             jnp.int32(0), jnp.int32(n_edges))
     want = bu_ref(*args)
-    got = bu_kernel(args[0], jnp.pad(args[1], (0, 512)), *args[2:], rt=32)
+    got = bu_kernel(args[0], jnp.pad(args[1], (0, 512)), *args[2:], rt=32,
+                    interpret=True)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     # invariants: completed rows never get parents; parents are in frontier
     out = np.asarray(got)

@@ -21,7 +21,7 @@ def test_embedding_bag_kernel(V, D, B, L, mode, dtype):
     w = jnp.asarray(rng.random((B, L)), jnp.float32)
     want = eb_ref.embedding_bag(table, jnp.asarray(ids), w, mode=mode)
     got = eb_ops.embedding_bag(table, jnp.asarray(ids), w, mode=mode,
-                               bt=min(32, B))
+                               bt=min(32, B), interpret=True)
     tol = 1e-6 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
@@ -31,7 +31,7 @@ def test_embedding_bag_kernel(V, D, B, L, mode, dtype):
 def test_embedding_bag_no_weights_all_padded():
     table = jnp.ones((16, 8), jnp.float32)
     ids = jnp.full((32, 4), -1, jnp.int32)
-    out = eb_ops.embedding_bag(table, ids, bt=32)
+    out = eb_ops.embedding_bag(table, ids, bt=32, interpret=True)
     np.testing.assert_array_equal(np.asarray(out), 0.0)
 
 
@@ -53,7 +53,8 @@ def test_flash_attention_kernel(Sq, Sk, dh, causal, window, q_off, dtype):
     want = fa_ref.attention(q, k, v, causal=causal, window=window,
                             q_offset=q_off)
     got = fa_ops.flash_attention(q, k, v, causal=causal, window=window,
-                                 q_offset=q_off, bq=64, bk=64)
+                                 q_offset=q_off, bq=64, bk=64,
+                                 interpret=True)
     tol = 2e-5 if dtype == jnp.float32 else 3e-2
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
@@ -75,7 +76,8 @@ def test_flash_matches_model_chunked_attention():
     qf = q.transpose(0, 2, 1, 3).reshape(B * Hq, S, dh)
     kf = jnp.repeat(k, rep, 2).transpose(0, 2, 1, 3).reshape(B * Hq, S, dh)
     vf = jnp.repeat(v, rep, 2).transpose(0, 2, 1, 3).reshape(B * Hq, S, dh)
-    out_k = fa_ops.flash_attention(qf, kf, vf, causal=True, bq=64, bk=64)
+    out_k = fa_ops.flash_attention(qf, kf, vf, causal=True, bq=64, bk=64,
+                                   interpret=True)
     out_k = out_k.reshape(B, Hq, S, dh).transpose(0, 2, 1, 3)
     np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_model),
                                rtol=2e-5, atol=2e-5)

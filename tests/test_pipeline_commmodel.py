@@ -1,4 +1,5 @@
 """Data-pipeline determinism + comm-model closed forms."""
+import jax
 import numpy as np
 
 from repro.configs.base import get_config, reduced
@@ -108,7 +109,6 @@ def test_uninstrumented_runs_carry_no_wire_counters():
     from jax.sharding import PartitionSpec as P
     from repro.configs.base import BFSConfig
     from repro.core.bfs import run_bfs
-    from repro.core.compat import shard_map
     from repro.core.steps_1d_sparse import sparse_exchange_1d
     from repro.graph.formats import build_blocked_1d
     from repro.graph.rmat import rmat_graph
@@ -137,8 +137,8 @@ def test_uninstrumented_runs_carry_no_wire_counters():
             captured["wire"] = wire
             return f_words[None]
 
-        shard_map(body, mesh=mesh, in_specs=(P("data"),),
-                  out_specs=P("data"), check_vma=False)(front)
+        jax.shard_map(body, mesh=mesh, in_specs=(P("data"),),
+                      out_specs=P("data"), check_vma=False)(front)
         return captured["wire"]
 
     assert wire_of(False) is None
