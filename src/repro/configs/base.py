@@ -225,10 +225,11 @@ class BFSConfig:
     direction_optimizing: bool = True
     # instrument=True compiles the full counter/level_stats bookkeeping
     # into the search program (Eq. 2 validation, crossover artifacts);
-    # instrument=False compiles it OUT and fuses the per-level scalar
-    # all-reduces the loop genuinely needs into ONE vector psum (+ one
-    # pmax under a pod axis) — the latency-lean fast path the paper's
-    # depth/time/TEPS runs use.  Parents are identical either way.
+    # instrument=False compiles the counters OUT and fuses the per-level
+    # scalar all-reduces the loop genuinely needs into ONE vector psum
+    # (+ one pmax under a pod axis) — the latency-lean fast path the
+    # paper's depth/time/TEPS runs use; its level_stats keep n_f, m_f,
+    # mode and used (NaN expand words).  Parents are identical either way.
     instrument: bool = True
     use_edge_dst: bool = False    # bottom-up O(E) row read (no searchsorted)
     compact_updates: bool = False  # bottom-up compact (child,parent) sends

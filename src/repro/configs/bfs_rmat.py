@@ -50,8 +50,9 @@ CONFIG_1DS = register(dataclasses.replace(
 CONFIG_1DS_RAW = register(dataclasses.replace(
     CONFIG_1DS, arch="bfs-rmat-1ds-raw", frontier_codec="none"))
 
-# --- Latency-lean fast path (instrument=False): counters/level_stats
-# compiled out, one fused scalar reduction per level, batched bottom-up
+# --- Latency-lean fast path (instrument=False): counters compiled out
+# (level_stats keep only what the loop reduces), one fused scalar
+# reduction per level, batched bottom-up
 # update exchange — the depth+time+TEPS configuration of the paper's §7
 # runs (see README "performance"; instrumented variants above exist for
 # Eq. 2 / crossover artifacts)

@@ -48,12 +48,14 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Callable, Dict, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import BFSConfig
 from repro.core.partition import Partition1D, Partition2D
+from repro.core.scopes import BOTTOMUP, REDUCE, TOPDOWN
 from repro.core.steps import (COUNTER_KEYS, LevelArgs, bottomup_level,
                               topdown_level, zero_counters)
 from repro.core.steps_1d import (LevelArgs1D, bottomup_level_1d,
@@ -78,7 +80,7 @@ class PlanStatics:
     #                           their top-down gather into this many
     #                           overlapped steps; 2d pipelines the
     #                           bottom-up ring (core/steps.py R/G split)
-    instrument: bool = True   # False: compile counters/level_stats OUT
+    instrument: bool = True   # False: compile the counters OUT
     #                           of the search program (the latency-lean
     #                           fast path; parents identical)
     interpret: bool = False   # Pallas kernels in the interpreter: derived
@@ -196,6 +198,19 @@ def unregister_decomposition(name: str) -> None:
 # ---------------------------------------------------------------------------
 
 
+def _level(mode, td_level, bu_level, *operands):
+    """One level in the direction ``mode`` picks (1: bottom-up), the
+    branch taken under its top scope (core/scopes.py)."""
+    def branch(scope, step):
+        def run(ops):
+            with jax.named_scope(scope):
+                return step(*ops)
+        return run
+
+    return lax.cond(mode == 1, branch(BOTTOMUP, bu_level),
+                    branch(TOPDOWN, td_level), operands)
+
+
 def _search_loop(g, gidx, root, *, n_total: float, cfg: BFSConfig, axes,
                  sync, td_level, bu_level, sync_modes: bool = False,
                  over_cap: int = 0, expand_chunks: int = 1):
@@ -249,37 +264,35 @@ def _search_loop(g, gidx, root, *, n_total: float, cfg: BFSConfig, axes,
 
     def body(st):
         pi, front, mode, level, n_f, n_sync, ctr, stats = st
-        m_f = lax.psum(jnp.sum(jnp.where(front, g["deg_A"], 0),
-                               dtype=jnp.float32), axes)
-        m_u = lax.psum(jnp.sum(jnp.where(pi == -1, g["deg_A"], 0),
-                               dtype=jnp.float32), axes)
-        if cfg.direction_optimizing:
-            # per-slice n_f: each batched search switches on its OWN
-            # frontier size, never a lockstep partner's
-            go_bu = (mode == 0) & (m_f > m_u / cfg.alpha)
-            go_td = (mode == 1) & (n_f < n_total / cfg.beta)
-            if sync_modes and sync != axes:
-                go_bu = lax.pmax(go_bu.astype(jnp.int32), sync) > 0
-                go_td = lax.pmin(go_td.astype(jnp.int32), sync) > 0
-            new_mode = jnp.where(go_bu, 1, jnp.where(go_td, 0, mode))
-        else:
-            new_mode = mode
+        with jax.named_scope(REDUCE):
+            m_f = lax.psum(jnp.sum(jnp.where(front, g["deg_A"], 0),
+                                   dtype=jnp.float32), axes)
+            m_u = lax.psum(jnp.sum(jnp.where(pi == -1, g["deg_A"], 0),
+                                   dtype=jnp.float32), axes)
+            if cfg.direction_optimizing:
+                # per-slice n_f: each batched search switches on its OWN
+                # frontier size, never a lockstep partner's
+                go_bu = (mode == 0) & (m_f > m_u / cfg.alpha)
+                go_td = (mode == 1) & (n_f < n_total / cfg.beta)
+                if sync_modes and sync != axes:
+                    go_bu = lax.pmax(go_bu.astype(jnp.int32), sync) > 0
+                    go_td = lax.pmin(go_td.astype(jnp.int32), sync) > 0
+                new_mode = jnp.where(go_bu, 1, jnp.where(go_td, 0, mode))
+            else:
+                new_mode = mode
 
-        pi2, front2, c2 = lax.cond(
-            new_mode == 1,
-            lambda pf: bu_level(pf[0], pf[1]),
-            lambda pf: td_level(pf[0], pf[1]),
-            (pi, front))
+        pi2, front2, c2 = _level(new_mode, td_level, bu_level, pi, front)
         ctr = {k: ctr[k] + c2[k] for k in ctr}
-        # stats row: n_f, m_f, mode, used, measured expand words this
-        # level (the dense-vs-sparse crossover is read off column 4)
-        stats = stats.at[level].set(
-            jnp.stack([n_f, m_f, new_mode.astype(jnp.float32),
-                       jnp.float32(1), c2["wire_expand"]]))
-        n_f2 = lax.psum(jnp.sum(front2, dtype=jnp.float32), axes)
-        # the predicate feeds on the cross-slice max so batched searches
-        # stay in lockstep; heuristics keep the per-slice n_f2
-        n_sync2 = lax.pmax(n_f2, sync) if sync != axes else n_f2
+        with jax.named_scope(REDUCE):
+            # stats row: n_f, m_f, mode, used, measured expand words this
+            # level (the dense-vs-sparse crossover is read off column 4)
+            stats = stats.at[level].set(
+                jnp.stack([n_f, m_f, new_mode.astype(jnp.float32),
+                           jnp.float32(1), c2["wire_expand"]]))
+            n_f2 = lax.psum(jnp.sum(front2, dtype=jnp.float32), axes)
+            # the predicate feeds on the cross-slice max so batched
+            # searches stay in lockstep; heuristics keep the per-slice n_f2
+            n_sync2 = lax.pmax(n_f2, sync) if sync != axes else n_f2
         return (pi2, front2, new_mode, level + 1, n_f2, n_sync2, ctr, stats)
 
     st = (pi0, front0, jnp.int32(0), jnp.int32(0), jnp.float32(1.0),
@@ -304,10 +317,13 @@ def _search_loop_fast(g, pi0, front0, *, n_total: float, cfg: BFSConfig,
     the post-level (pi, front) — the same values the instrumented loop
     recomputes with separate psums at the top of L+1 — so the mode
     sequence and the parents are bit-identical to the instrumented
-    program.  Counters and level_stats are compiled out; the returned
-    ctr is EMPTY (a fast run has no measurements — zeros here would
-    masquerade as measured wire volumes downstream) and stats are
-    constant zeros."""
+    program.  Counters are compiled out; the returned ctr is EMPTY (a
+    fast run has no measurements — zeros here would masquerade as
+    measured wire volumes downstream).  The level_stats rows carry what
+    the loop already reduces — the level's own (per-slice) n_f and m_f,
+    its direction and used = 1, bit-identical to the instrumented rows
+    — and NaN in column 4, the expand words this program does not
+    measure.  The row write adds no collective."""
     deg = g["deg_A"]
 
     def reduce_state(pi, front):
@@ -346,32 +362,39 @@ def _search_loop_fast(g, pi0, front0, *, n_total: float, cfg: BFSConfig,
             return pm[0], pm[1] > 0, pm[2] < 1
         return lax.pmax(n_f, sync), go_bu, go_td
 
-    n_f0, m_f0, m_u0, ov0 = reduce_state(pi0, front0)
-    n_sync0, gb0, gt0 = decide_and_sync(jnp.int32(0), n_f0, m_f0, m_u0)
+    with jax.named_scope(REDUCE):
+        n_f0, m_f0, m_u0, ov0 = reduce_state(pi0, front0)
+        n_sync0, gb0, gt0 = decide_and_sync(jnp.int32(0), n_f0, m_f0, m_u0)
+    # column 4 (measured expand words) is not measured here: NaN, never 0
+    stats0 = jnp.zeros((MAX_LEVELS, 5), jnp.float32).at[:, 4].set(jnp.nan)
 
     def cond(st):
-        pi, front, mode, level, n_sync, gb, gt, ov = st
-        return (level < MAX_LEVELS) & (n_sync > 0)
+        return (st["level"] < MAX_LEVELS) & (st["n_sync"] > 0)
 
     def body(st):
-        pi, front, mode, level, n_sync, gb, gt, ov = st
-        if cfg.direction_optimizing:
-            new_mode = jnp.where(gb, 1, jnp.where(gt, 0, mode))
-        else:
-            new_mode = mode
-        pi2, front2, _ = lax.cond(
-            new_mode == 1,
-            lambda op: bu_level(op[0], op[1], {"over": op[2]}),
-            lambda op: td_level(op[0], op[1], {"over": op[2]}),
-            (pi, front, ov))
-        n_f2, m_f2, m_u2, ov2 = reduce_state(pi2, front2)
-        n_sync2, gb2, gt2 = decide_and_sync(new_mode, n_f2, m_f2, m_u2)
-        return (pi2, front2, new_mode, level + 1, n_sync2, gb2, gt2, ov2)
+        with jax.named_scope(REDUCE):
+            if cfg.direction_optimizing:
+                new_mode = jnp.where(st["gb"], 1,
+                                     jnp.where(st["gt"], 0, st["mode"]))
+            else:
+                new_mode = st["mode"]
+        pi2, front2, _ = _level(new_mode, td_level, bu_level, st["pi"],
+                                st["front"], {"over": st["ov"]})
+        with jax.named_scope(REDUCE):
+            n_f2, m_f2, m_u2, ov2 = reduce_state(pi2, front2)
+            n_sync2, gb2, gt2 = decide_and_sync(new_mode, n_f2, m_f2, m_u2)
+            stats = st["stats"].at[st["level"]].set(jnp.stack([
+                st["n_f"], st["m_f"], new_mode.astype(jnp.float32),
+                jnp.float32(1), jnp.float32(jnp.nan)]))
+        return dict(pi=pi2, front=front2, mode=new_mode,
+                    level=st["level"] + 1, n_f=n_f2, m_f=m_f2,
+                    n_sync=n_sync2, gb=gb2, gt=gt2, ov=ov2, stats=stats)
 
-    st = (pi0, front0, jnp.int32(0), jnp.int32(0), n_sync0, gb0, gt0, ov0)
-    pi, front, mode, level, n_sync, gb, gt, ov = lax.while_loop(
-        cond, body, st)
-    return pi, level, {}, jnp.zeros((MAX_LEVELS, 5), jnp.float32)
+    st = lax.while_loop(cond, body, dict(
+        pi=pi0, front=front0, mode=jnp.int32(0), level=jnp.int32(0),
+        n_f=n_f0, m_f=m_f0, n_sync=n_sync0, gb=gb0, gt=gt0, ov=ov0,
+        stats=stats0))
+    return st["pi"], st["level"], {}, st["stats"]
 
 
 # ---------------------------------------------------------------------------

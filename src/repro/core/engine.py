@@ -50,6 +50,7 @@ from repro.core import comm_model
 from repro.core.decomp import (Decomposition, PlanStatics,
                                get_decomposition)
 from repro.core.local_ops import LocalOps, get_local_ops
+from repro.core.scopes import op_scope
 
 
 @dataclass
@@ -58,7 +59,8 @@ class BFSResult:
     n_levels: int
     counters: Dict[str, float]   # whole-search totals (paper 64-bit words)
     level_stats: np.ndarray      # (MAX_LEVELS, 5): n_f, m_f, mode, used,
-    #                              measured expand words that level
+    #                              measured expand words that level (NaN
+    #                              when the program is not instrumented)
     validation: Optional[Any] = None  # ValidationReport when run(...,
     #                              validate=True); None otherwise
 
@@ -325,6 +327,27 @@ def hlo_collective_counts(hlo: str) -> Dict[str, int]:
     return counts
 
 
+# one HLO instruction and its op_name metadata, compiled
+# (`  [ROOT ]%fusion.3 = s32[8]{0} fusion(...), ..., metadata={op_name="..."`)
+# or lowered with debug info (the same without the `%`)
+_INSTR_OP_NAME_RE = re.compile(
+    r"^\s*(?:ROOT\s+)?%?([^\s=]+)\s*=.*?\bmetadata=\{[^\n]*?"
+    r"\bop_name=\"([^\"]*)\"", re.MULTILINE)
+
+
+def hlo_op_scopes(hlo: str) -> Dict[str, str]:
+    """{instruction name: scope path} for every instruction of an HLO
+    text (compiled, or lowered with ``debug_info=True``) whose op_name
+    lies under a level-program scope (core/scopes.py); instructions
+    outside every scope are left out."""
+    out = {}
+    for m in _INSTR_OP_NAME_RE.finditer(hlo):
+        scope = op_scope(m.group(2))
+        if scope is not None:
+            out[m.group(1)] = scope
+    return out
+
+
 class BFSEngine:
     """A compiled traversal session: graph shipped once, program
     compiled once, traversed from many roots.
@@ -386,16 +409,23 @@ class BFSEngine:
 
     @property
     def instrument(self) -> bool:
-        """Whether the compiled search program carries the counter /
-        level_stats bookkeeping (plan-level; see BFSConfig.instrument).
+        """Whether the compiled search program carries the counters
+        and measured expand words (plan-level; see BFSConfig.instrument).
         False = the latency-lean fast path: one fused scalar reduction
-        per level, zero counters in the results."""
+        per level, no counters in the results."""
         return self.plan.statics.instrument
 
     def collective_counts(self) -> Dict[str, int]:
         """Collective-op counts of the compiled single-root search (the
         static schedule the fast path exists to shrink)."""
         return hlo_collective_counts(self._exec.as_text())
+
+    def op_scopes(self) -> Dict[str, str]:
+        """{HLO instruction name: scope path} of the compiled
+        single-root search, read from each instruction's op_name: what
+        names a device op of a profiler trace by its phase of the level
+        program ("bfs.bottomup/discover/edge_rows", ...)."""
+        return hlo_op_scopes(self._exec.as_text())
 
     def _check_root(self, root) -> int:
         """Graphs are padded up to p*chunk vertices; a root in the padded

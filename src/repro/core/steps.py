@@ -25,6 +25,7 @@ from jax import lax
 from repro.core import comm_model
 from repro.core.frontier import (INT_INF, expand_bitmap, pack_bits,
                                  unpack_bits)
+from repro.core.scopes import DISCOVER, EXPAND, FOLD, UPDATE
 
 COUNTER_KEYS = ("wire_transpose", "wire_expand", "wire_fold", "wire_rotate",
                 "wire_updates", "use_expand", "use_fold", "use_rotate",
@@ -52,7 +53,7 @@ class LevelArgs(NamedTuple):
     compact_updates: bool = False  # bottom-up: compact (child,parent) sends
     cap_u: int = 0            # compact updates capacity (0 = chunk//8)
     ops: "object" = None      # LocalOps entry (None = look up from strings)
-    instrument: bool = True   # False: compile out counters/level_stats
+    instrument: bool = True   # False: compile out the counters
     #                           (the latency-lean fast path; parents
     #                           identical, ctr returned empty)
     # > 1 switches the bottom-up systolic rotation to the software-
@@ -180,67 +181,70 @@ def topdown_level(g: Dict[str, jax.Array], pi: jax.Array, front: jax.Array,
     ctr = zero_counters() if instr else {}
 
     # --- Expand: transpose + allgather along processor column ------------
-    f_words, wire = expand_bitmap(front, args.perm,
-                                  (args.row_axis, args.col_axis))
-    f_cj = unpack_bits(f_words)                      # (nc,) bool
-    if instr:
-        n_f = lax.psum(jnp.sum(front, dtype=jnp.float32),
-                       (args.row_axis, args.col_axis))
-        ctr["wire_transpose"] = jnp.float32(chunk / 64.0) * p
-        ctr["wire_expand"] = wire * p - ctr["wire_transpose"]
-        ctr["use_expand"] = n_f * (pr - 1)           # sparse ids, replicated
+    with jax.named_scope(EXPAND):
+        f_words, wire = expand_bitmap(front, args.perm,
+                                      (args.row_axis, args.col_axis))
+        f_cj = unpack_bits(f_words)                      # (nc,) bool
+        if instr:
+            n_f = lax.psum(jnp.sum(front, dtype=jnp.float32),
+                           (args.row_axis, args.col_axis))
+            ctr["wire_transpose"] = jnp.float32(chunk / 64.0) * p
+            ctr["wire_expand"] = wire * p - ctr["wire_transpose"]
+            ctr["use_expand"] = n_f * (pr - 1)       # sparse ids, replicated
 
     # --- Local discovery: SpMSV in the (select-source, min) semiring -----
-    # format-specific work lives behind the LocalOps entry (CSR/DCSC x
-    # dense/kernel); the step only owns the collectives and counters
-    j = lax.axis_index(args.col_axis)
-    col_offset = (j * nc).astype(jnp.int32)
-    cand, ex_local = _resolve_ops(args).topdown(g, f_words, f_cj, nr,
-                                                col_offset, args)
-    if instr:
-        ctr["edges_examined"] = lax.psum(ex_local,
-                                         (args.row_axis, args.col_axis))
-        m_f = lax.psum(jnp.sum(jnp.where(front, g["deg_A"], 0),
-                               dtype=jnp.float32),
-                       (args.row_axis, args.col_axis))
-        ctr["edges_useful"] = m_f
+    with jax.named_scope(DISCOVER):
+        # format-specific work lives behind the LocalOps entry (CSR/DCSC x
+        # dense/kernel); the step only owns the collectives and counters
+        j = lax.axis_index(args.col_axis)
+        col_offset = (j * nc).astype(jnp.int32)
+        cand, ex_local = _resolve_ops(args).topdown(g, f_words, f_cj, nr,
+                                                    col_offset, args)
+        if instr:
+            ctr["edges_examined"] = lax.psum(ex_local,
+                                             (args.row_axis, args.col_axis))
+            m_f = lax.psum(jnp.sum(jnp.where(front, g["deg_A"], 0),
+                                   dtype=jnp.float32),
+                           (args.row_axis, args.col_axis))
+            ctr["edges_useful"] = m_f
 
     # --- Fold: exchange candidates along the processor row ---------------
-    if args.fold_mode == "alltoall":
-        t = _fold_alltoall(cand, pc, chunk, args.col_axis)
+    with jax.named_scope(FOLD):
+        if args.fold_mode == "alltoall":
+            t = _fold_alltoall(cand, pc, chunk, args.col_axis)
+            if instr:
+                ctr["wire_fold"] = jnp.float32((pc - 1) * chunk) * p
+        elif args.fold_mode in ("bitmap", "bitmap_pure"):
+            cap_w = args.cap_w or max(chunk // 16, 32)
+            t, my_wins = _fold_bitmap(cand, pc, chunk, args.col_axis, cap_w)
+            if args.fold_mode == "bitmap":
+                # runtime fallback: a source chunk overflowing cap_w wins
+                # re-runs the dense fold (compiled but executed only then).
+                # NB: the predicate must be GLOBALLY consistent — the branch
+                # contains collectives that lower as whole-mesh ops.
+                overflow = lax.pmax(
+                    jnp.max(jnp.sum(my_wins, axis=1)),
+                    (args.row_axis, args.col_axis)) > cap_w
+                t = lax.cond(overflow,
+                             lambda c: _fold_alltoall(c, pc, chunk,
+                                                      args.col_axis),
+                             lambda c: t, cand)
+            if instr:
+                ctr["wire_fold"] = jnp.float32(
+                    comm_model.fold_bitmap_level_words(pc * chunk, pc,
+                                                       cap_w)) * p
+        else:
+            t = _fold_ring_reduce(cand, pc, chunk, args.col_axis)
+            if instr:
+                ctr["wire_fold"] = jnp.float32((pc - 1) * chunk) * p
         if instr:
-            ctr["wire_fold"] = jnp.float32((pc - 1) * chunk) * p
-    elif args.fold_mode in ("bitmap", "bitmap_pure"):
-        cap_w = args.cap_w or max(chunk // 16, 32)
-        t, my_wins = _fold_bitmap(cand, pc, chunk, args.col_axis, cap_w)
-        if args.fold_mode == "bitmap":
-            # runtime fallback: a source chunk overflowing cap_w wins
-            # re-runs the dense fold (compiled but executed only then).
-            # NB: the predicate must be GLOBALLY consistent — the branch
-            # contains collectives that lower as whole-mesh ops.
-            overflow = lax.pmax(
-                jnp.max(jnp.sum(my_wins, axis=1)),
-                (args.row_axis, args.col_axis)) > cap_w
-            t = lax.cond(overflow,
-                         lambda c: _fold_alltoall(c, pc, chunk,
-                                                  args.col_axis),
-                         lambda c: t, cand)
-        if instr:
-            ctr["wire_fold"] = jnp.float32(
-                comm_model.fold_bitmap_level_words(pc * chunk, pc,
-                                                   cap_w)) * p
-    else:
-        t = _fold_ring_reduce(cand, pc, chunk, args.col_axis)
-        if instr:
-            ctr["wire_fold"] = jnp.float32((pc - 1) * chunk) * p
-    if instr:
-        n_cand = lax.psum(jnp.sum(cand != INT_INF, dtype=jnp.float32),
-                          (args.row_axis, args.col_axis))
-        ctr["use_fold"] = 2.0 * n_cand               # (child, parent) pairs
+            n_cand = lax.psum(jnp.sum(cand != INT_INF, dtype=jnp.float32),
+                              (args.row_axis, args.col_axis))
+            ctr["use_fold"] = 2.0 * n_cand           # (child, parent) pairs
 
-    # --- Local update -----------------------------------------------------
-    newly = (pi == -1) & (t != INT_INF)
-    pi = jnp.where(newly, t, pi)
+        # --- Local update -------------------------------------------------
+        newly = (pi == -1) & (t != INT_INF)
+        pi = jnp.where(newly, t, pi)
     return pi, newly, ctr
 
 
@@ -297,175 +301,186 @@ def bottomup_level(g: Dict[str, jax.Array], pi: jax.Array, front: jax.Array,
     ctr = zero_counters() if instr else {}
 
     # --- Gather frontier (dense bitmap; per level) ------------------------
-    f_words, wire = expand_bitmap(front, args.perm, axes)
-    if instr:
-        ctr["wire_transpose"] = jnp.float32(chunk / 64.0) * p
-        ctr["wire_expand"] = wire * p - ctr["wire_transpose"]
-        ctr["use_expand"] = jnp.float32(chunk / 64.0 * (1 + (pr - 1))) * p
+    with jax.named_scope(EXPAND):
+        f_words, wire = expand_bitmap(front, args.perm, axes)
+        if instr:
+            ctr["wire_transpose"] = jnp.float32(chunk / 64.0) * p
+            ctr["wire_expand"] = wire * p - ctr["wire_transpose"]
+            ctr["use_expand"] = jnp.float32(chunk / 64.0 * (1 + (pr - 1))) * p
 
-    j = lax.axis_index(args.col_axis)
-    cseg = pi != -1                       # completed = has parent (own chunk)
+    with jax.named_scope(DISCOVER):
+        j = lax.axis_index(args.col_axis)
+        cseg = pi != -1                   # completed = has parent (own chunk)
 
-    rot_perm = [(q, (q + 1) % pc) for q in range(pc)]
-    edges_use = jnp.float32(0)
+        rot_perm = [(q, (q + 1) % pc) for q in range(pc)]
+        edges_use = jnp.float32(0)
 
-    col_offset = (j * nc).astype(jnp.int32)
-    pure = args.fold_mode.endswith("_pure")
-    compact = args.compact_updates
-    cap_u = args.cap_u or max(chunk // 8, 32)
-    ops = _resolve_ops(args)
+        col_offset = (j * nc).astype(jnp.int32)
+        pure = args.fold_mode.endswith("_pure")
+        compact = args.compact_updates
+        cap_u = args.cap_u or max(chunk // 8, 32)
+        ops = _resolve_ops(args)
 
-    # per-destination accumulation for the level-end batched exchange
-    # (compact mode never holds sub-step 0: the self segment pays no
-    # wire and must not be capacity-truncated — it rides the self slot)
-    if compact:
-        send_i = jnp.full((pc, cap_u), chunk, jnp.int32)
-        send_v = jnp.full((pc, cap_u), INT_INF, jnp.int32)
-    if (not compact) or (not pure):
-        send_d = jnp.full((pc, chunk), INT_INF, jnp.int32)
-    self_par = None
-    max_found = jnp.int32(0)
-    carry = None
-    pipelined = args.expand_chunks > 1
-    if pipelined:
-        # R/G split ring: R (in ``carry``) rotates the pre-level
-        # completed bitmap — a payload with no scan dependency — while
-        # g_acc carries the accumulated this-level finds for the
-        # post-scan filter.
-        carry = pack_bits(cseg)
-        g_acc = jnp.zeros((chunk // 32,), jnp.uint32)
-    g_seen = None
+        # per-destination accumulation for the level-end batched exchange
+        # (compact mode never holds sub-step 0: the self segment pays no
+        # wire and must not be capacity-truncated — it rides the self slot)
+        if compact:
+            send_i = jnp.full((pc, cap_u), chunk, jnp.int32)
+            send_v = jnp.full((pc, cap_u), INT_INF, jnp.int32)
+        if (not compact) or (not pure):
+            send_d = jnp.full((pc, chunk), INT_INF, jnp.int32)
+        self_par = None
+        max_found = jnp.int32(0)
+        carry = None
+        pipelined = args.expand_chunks > 1
+        if pipelined:
+            # R/G split ring: R (in ``carry``) rotates the pre-level
+            # completed bitmap — a payload with no scan dependency — while
+            # g_acc carries the accumulated this-level finds for the
+            # post-scan filter.
+            carry = pack_bits(cseg)
+            g_acc = jnp.zeros((chunk // 32,), jnp.uint32)
+        g_seen = None
 
     for s in range(pc):
         if s > 0:
-            # hoisted rotation: issued ahead of this sub-step's slicing
-            # and local scan so the async permute overlaps them
-            if pipelined:
-                # R is known since the PREVIOUS sub-step's start, so
-                # this permute overlaps the previous scan as well; the
-                # G permute's result is not consumed until after THIS
-                # sub-step's scan — neither blocks the Pallas scan
-                carry = lax.ppermute(carry, args.col_axis, rot_perm)
-                g_in = lax.ppermute(g_acc, args.col_axis, rot_perm)
-                cseg = unpack_bits(carry)
-            else:
-                cseg = unpack_bits(lax.ppermute(carry, args.col_axis,
-                                                rot_perm))
-            if instr:
-                ctr["wire_rotate"] += jnp.float32(
-                    (2 if pipelined else 1) * chunk / 64.0) * p
-                ctr["use_rotate"] += jnp.float32(chunk / 64.0) * p
+            # the ring rotation of the completed bitmap is exchange, so it
+            # runs under expand: discover covers the local scan alone
+            with jax.named_scope(EXPAND):
+                # hoisted rotation: issued ahead of this sub-step's slicing
+                # and local scan so the async permute overlaps them
+                if pipelined:
+                    # R is known since the PREVIOUS sub-step's start, so
+                    # this permute overlaps the previous scan as well; the
+                    # G permute's result is not consumed until after THIS
+                    # sub-step's scan — neither blocks the Pallas scan
+                    carry = lax.ppermute(carry, args.col_axis, rot_perm)
+                    g_in = lax.ppermute(g_acc, args.col_axis, rot_perm)
+                    cseg = unpack_bits(carry)
+                else:
+                    cseg = unpack_bits(lax.ppermute(carry, args.col_axis,
+                                                    rot_perm))
+                if instr:
+                    ctr["wire_rotate"] += jnp.float32(
+                        (2 if pipelined else 1) * chunk / 64.0) * p
+                    ctr["use_rotate"] += jnp.float32(chunk / 64.0) * p
         elif pipelined:
             g_in = g_acc                  # no prior finds at sub-step 0
-        seg_id = (j - s) % pc             # segment V_{i, j-s} this sub-step
-        e0 = lax.dynamic_index_in_dim(g["seg_ptr"], seg_id, keepdims=False)
-        e1 = lax.dynamic_index_in_dim(g["seg_ptr"], seg_id + 1, keepdims=False)
-        rp_seg = (lax.dynamic_slice_in_dim(g["row_ptr"], seg_id * chunk,
-                                           chunk + 1) - e0).astype(jnp.int32)
-        ue = lax.dynamic_slice_in_dim(g["col_idx"], e0, args.cap_seg)
-        n_edges = (e1 - e0).astype(jnp.int32)
-        cvec = cseg.astype(jnp.int32)
-        ve = (lax.dynamic_slice_in_dim(g["edge_dst"], e0, args.cap_seg)
-              - seg_id * chunk) if args.use_edge_dst and "edge_dst" in g \
-            else None
-        seg_par = ops.bottomup(rp_seg, ue, f_words, cvec, col_offset,
-                               n_edges, ve, args)
-        found = seg_par != INT_INF
-        if pipelined:
-            # exactness post-filter: the scan above used the stale
-            # R-only bitmap, so rows discovered by earlier sub-steps
-            # (the G chain, arriving here — after the scan) may have
-            # been re-found; mask them out.  Per-row results are
-            # independent of other rows' cvec, so the surviving finds
-            # are bit-identical to the exact-bitmap scan.
-            g_seen = unpack_bits(g_in)
-            found = found & ~g_seen
-            seg_par = jnp.where(found, seg_par, INT_INF)
-        row_lens = (rp_seg[1:] - rp_seg[:-1]).astype(jnp.float32)
-        if instr:
-            # scanned-row accounting uses the EXACT completed view (R|G
-            # when pipelined) so counters match the classic schedule
-            unknown = (cvec == 0) if not pipelined else ~(cseg | g_seen)
-            edges_use += lax.psum(
-                jnp.sum(jnp.where(unknown, row_lens, 0.0)), axes)
+        with jax.named_scope(DISCOVER):
+            seg_id = (j - s) % pc         # segment V_{i, j-s} this sub-step
+            e0 = lax.dynamic_index_in_dim(g["seg_ptr"], seg_id, keepdims=False)
+            e1 = lax.dynamic_index_in_dim(g["seg_ptr"], seg_id + 1,
+                                          keepdims=False)
+            rp_seg = (lax.dynamic_slice_in_dim(g["row_ptr"], seg_id * chunk,
+                                               chunk + 1)
+                      - e0).astype(jnp.int32)
+            ue = lax.dynamic_slice_in_dim(g["col_idx"], e0, args.cap_seg)
+            n_edges = (e1 - e0).astype(jnp.int32)
+            cvec = cseg.astype(jnp.int32)
+            ve = (lax.dynamic_slice_in_dim(g["edge_dst"], e0, args.cap_seg)
+                  - seg_id * chunk) if args.use_edge_dst and "edge_dst" in g \
+                else None
+            seg_par = ops.bottomup(rp_seg, ue, f_words, cvec, col_offset,
+                                   n_edges, ve, args)
+            found = seg_par != INT_INF
+            if pipelined:
+                # exactness post-filter: the scan above used the stale
+                # R-only bitmap, so rows discovered by earlier sub-steps
+                # (the G chain, arriving here — after the scan) may have
+                # been re-found; mask them out.  Per-row results are
+                # independent of other rows' cvec, so the surviving finds
+                # are bit-identical to the exact-bitmap scan.
+                g_seen = unpack_bits(g_in)
+                found = found & ~g_seen
+                seg_par = jnp.where(found, seg_par, INT_INF)
+            row_lens = (rp_seg[1:] - rp_seg[:-1]).astype(jnp.float32)
+            if instr:
+                # scanned-row accounting uses the EXACT completed view (R|G
+                # when pipelined) so counters match the classic schedule
+                unknown = (cvec == 0) if not pipelined else ~(cseg | g_seen)
+                edges_use += lax.psum(
+                    jnp.sum(jnp.where(unknown, row_lens, 0.0)), axes)
 
-        # Accumulate the update segment for its layout-A owner (the
-        # s=0 self segment never enters the buffers: it pays no wire
-        # and lands in the self slot after the exchange)
-        if s == 0:
-            self_par = seg_par
-        else:
-            if compact:
-                # beyond-paper: ship only discovered (child, parent)
-                # pairs (static capacity; level-end fallback to the
-                # dense segments)
-                cidx = jnp.where(found, size=cap_u,
-                                 fill_value=chunk)[0].astype(jnp.int32)
-                cval = seg_par[jnp.minimum(cidx, chunk - 1)]
-                send_i = lax.dynamic_update_slice(send_i, cidx[None],
-                                                  (seg_id, jnp.int32(0)))
-                send_v = lax.dynamic_update_slice(send_v, cval[None],
-                                                  (seg_id, jnp.int32(0)))
-                if not pure:
-                    max_found = jnp.maximum(
-                        max_found, jnp.sum(found, dtype=jnp.int32))
-                if instr:
-                    ctr["wire_updates"] += jnp.float32(2 * cap_u) * p
-            if (not compact) or (not pure):
-                send_d = lax.dynamic_update_slice(send_d, seg_par[None],
-                                                  (seg_id, jnp.int32(0)))
-            if instr and not compact:
-                ctr["wire_updates"] += jnp.float32(chunk) * p
-        if instr:
-            n_upd = lax.psum(jnp.sum(found, dtype=jnp.float32), axes)
-            ctr["use_updates"] += 2.0 * n_upd
+            # Accumulate the update segment for its layout-A owner (the
+            # s=0 self segment never enters the buffers: it pays no wire
+            # and lands in the self slot after the exchange)
+            if s == 0:
+                self_par = seg_par
+            else:
+                if compact:
+                    # beyond-paper: ship only discovered (child, parent)
+                    # pairs (static capacity; level-end fallback to the
+                    # dense segments)
+                    cidx = jnp.where(found, size=cap_u,
+                                     fill_value=chunk)[0].astype(jnp.int32)
+                    cval = seg_par[jnp.minimum(cidx, chunk - 1)]
+                    send_i = lax.dynamic_update_slice(send_i, cidx[None],
+                                                      (seg_id, jnp.int32(0)))
+                    send_v = lax.dynamic_update_slice(send_v, cval[None],
+                                                      (seg_id, jnp.int32(0)))
+                    if not pure:
+                        max_found = jnp.maximum(
+                            max_found, jnp.sum(found, dtype=jnp.int32))
+                    if instr:
+                        ctr["wire_updates"] += jnp.float32(2 * cap_u) * p
+                if (not compact) or (not pure):
+                    send_d = lax.dynamic_update_slice(send_d, seg_par[None],
+                                                      (seg_id, jnp.int32(0)))
+                if instr and not compact:
+                    ctr["wire_updates"] += jnp.float32(chunk) * p
+            if instr:
+                n_upd = lax.psum(jnp.sum(found, dtype=jnp.float32), axes)
+                ctr["use_updates"] += 2.0 * n_upd
 
-        # Mark discoveries in the carried bitmap; the rotation itself is
-        # issued at the top of the next sub-step (hoisted)
-        if pipelined:
-            g_acc = pack_bits(g_seen | found)   # R rides carry unchanged
-        else:
-            cseg = cseg | found
-            if s != pc - 1:
-                carry = pack_bits(cseg)
+            # Mark discoveries in the carried bitmap; the rotation itself is
+            # issued at the top of the next sub-step (hoisted)
+            if pipelined:
+                g_acc = pack_bits(g_seen | found)   # R rides carry unchanged
+            else:
+                cseg = cseg | found
+                if s != pc - 1:
+                    carry = pack_bits(cseg)
 
     # --- Batched update exchange (one tiled all_to_all) -------------------
-    def _a2a(x):
-        return lax.all_to_all(x, args.col_axis, split_axis=0, concat_axis=0)
+    with jax.named_scope(UPDATE):
+        def _a2a(x):
+            return lax.all_to_all(x, args.col_axis, split_axis=0,
+                                  concat_axis=0)
 
-    def _scatter_compact(si, sv):
-        # idx+val ride one exchange; sentinel idx == chunk drops
-        r = _a2a(jnp.concatenate([si, sv], axis=1))       # (pc, 2*cap_u)
-        rows = jnp.arange(pc, dtype=jnp.int32)[:, None]
-        return jnp.full((pc, chunk), INT_INF, jnp.int32).at[
-            rows, r[:, :cap_u]].min(r[:, cap_u:], mode="drop")
+        def _scatter_compact(si, sv):
+            # idx+val ride one exchange; sentinel idx == chunk drops
+            r = _a2a(jnp.concatenate([si, sv], axis=1))       # (pc, 2*cap_u)
+            rows = jnp.arange(pc, dtype=jnp.int32)[:, None]
+            return jnp.full((pc, chunk), INT_INF, jnp.int32).at[
+                rows, r[:, :cap_u]].min(r[:, cap_u:], mode="drop")
 
-    if compact and pure:
-        recv = _scatter_compact(send_i, send_v)
-    elif compact:
-        # global predicate: any sub-step's discoveries overflowing cap_u
-        # re-ships the whole level dense (the branch collectives are
-        # whole-mesh ops, so the predicate must be globally consistent)
-        over = lax.pmax(max_found, axes) > cap_u
-        recv = lax.cond(over,
-                        lambda b: _a2a(b[0]),
-                        lambda b: _scatter_compact(b[1], b[2]),
-                        (send_d, send_i, send_v))
-    else:
-        recv = _a2a(send_d)
-    # the self slot always carries sub-step 0's dense segment
-    recv = lax.dynamic_update_slice(recv, self_par[None], (j, jnp.int32(0)))
+        if compact and pure:
+            recv = _scatter_compact(send_i, send_v)
+        elif compact:
+            # global predicate: any sub-step's discoveries overflowing cap_u
+            # re-ships the whole level dense (the branch collectives are
+            # whole-mesh ops, so the predicate must be globally consistent)
+            over = lax.pmax(max_found, axes) > cap_u
+            recv = lax.cond(over,
+                            lambda b: _a2a(b[0]),
+                            lambda b: _scatter_compact(b[1], b[2]),
+                            (send_d, send_i, send_v))
+        else:
+            recv = _a2a(send_d)
+        # the self slot always carries sub-step 0's dense segment
+        recv = lax.dynamic_update_slice(recv, self_par[None],
+                                        (j, jnp.int32(0)))
 
-    # --- Apply updates in sub-step order (source q ran sub-step (q-j)%pc
-    # for this chunk, so s-order application matches the old sequential
-    # per-sub-step semantics exactly) ---------------------------------------
-    new_front = jnp.zeros_like(front)
-    new_pi = pi
-    for s in range(pc):
-        upd = lax.dynamic_slice_in_dim(recv, (j + s) % pc, 1, axis=0)[0]
-        newly = (upd != INT_INF) & (new_pi == -1)
-        new_pi = jnp.where(newly, upd, new_pi)
-        new_front = new_front | newly
+        # --- Apply updates in sub-step order (source q ran sub-step (q-j)%pc
+        # for this chunk, so s-order application matches the old sequential
+        # per-sub-step semantics exactly) -----------------------------------
+        new_front = jnp.zeros_like(front)
+        new_pi = pi
+        for s in range(pc):
+            upd = lax.dynamic_slice_in_dim(recv, (j + s) % pc, 1, axis=0)[0]
+            newly = (upd != INT_INF) & (new_pi == -1)
+            new_pi = jnp.where(newly, upd, new_pi)
+            new_front = new_front | newly
 
     if instr:
         ctr["edges_useful"] = edges_use

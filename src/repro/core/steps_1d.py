@@ -36,6 +36,7 @@ from jax import lax
 
 from repro.core import comm_model
 from repro.core.frontier import INT_INF, pack_bits, unpack_bits
+from repro.core.scopes import DISCOVER, EXPAND, FOLD, UPDATE
 from repro.core.steps import zero_counters
 
 
@@ -52,7 +53,7 @@ class LevelArgs1D(NamedTuple):
     cap_f: int = 0            # kernel csr: frontier capacity (0 = n)
     maxdeg: int = 0           # kernel mode: max column-segment length
     ops: "object" = None      # LocalOps entry (None = look up from strings)
-    instrument: bool = True   # False: compile out counters/level_stats
+    instrument: bool = True   # False: compile out the counters
     # software-pipelined expand: split the top-down allgather into this
     # many sub-chunk collectives, consuming sub-chunk k while k+1 is in
     # flight (1 = the classic single-gather schedule)
@@ -140,14 +141,17 @@ def pipelined_expand_consume(g, sub_gather, n_chunks: int,
     (select-source, min) semiring); edges-examined sums."""
     cand = jnp.full((args.part.chunk,), INT_INF, jnp.int32)
     ex = jnp.float32(0.0)
-    nxt = sub_gather(0)
+    with jax.named_scope(EXPAND):
+        nxt = sub_gather(0)
     for k in range(n_chunks):
         cur = nxt
         if k + 1 < n_chunks:
-            nxt = sub_gather(k + 1)     # in flight during the consume below
-        c_k, e_k = _consume_subchunk(g, cur, k, n_chunks, args)
-        cand = jnp.minimum(cand, c_k)
-        ex = ex + e_k
+            with jax.named_scope(EXPAND):
+                nxt = sub_gather(k + 1)  # in flight during the consume
+        with jax.named_scope(DISCOVER):
+            c_k, e_k = _consume_subchunk(g, cur, k, n_chunks, args)
+            cand = jnp.minimum(cand, c_k)
+            ex = ex + e_k
     return cand, ex
 
 
@@ -182,14 +186,15 @@ def topdown_level_1d(g: Dict[str, jax.Array], pi: jax.Array,
         cand, ex_local, wire = _pipelined_topdown_expand_1d(g, front, args)
     else:
         # --- Expand: allgather the frontier bitmap along the axis --------
-        f_words, wire = expand_frontier_1d(front, args.axis)
-        f_all = unpack_bits(f_words)                 # (n,) bool
+        with jax.named_scope(EXPAND):
+            f_words, wire = expand_frontier_1d(front, args.axis)
+            f_all = unpack_bits(f_words)             # (n,) bool
         # --- Local discovery: SpMSV over the strip (global source ids, so
         # col_offset = 0; format-specific work lives in the LocalOps
         # entry) --
-        cand, ex_local = _resolve_ops(args).topdown(g, f_words, f_all,
-                                                    part.chunk, jnp.int32(0),
-                                                    args)
+        with jax.named_scope(DISCOVER):
+            cand, ex_local = _resolve_ops(args).topdown(
+                g, f_words, f_all, part.chunk, jnp.int32(0), args)
     if instr:
         ctr["wire_expand"] = wire
         n_f = lax.psum(jnp.sum(front, dtype=jnp.float32), args.axis)
@@ -200,8 +205,9 @@ def topdown_level_1d(g: Dict[str, jax.Array], pi: jax.Array,
             args.axis)
 
     # --- Local update (children are owned; no fold) ----------------------
-    newly = (pi == -1) & (cand != INT_INF)
-    pi = jnp.where(newly, cand, pi)
+    with jax.named_scope(FOLD):
+        newly = (pi == -1) & (cand != INT_INF)
+        pi = jnp.where(newly, cand, pi)
     return pi, newly, ctr
 
 
@@ -216,19 +222,23 @@ def bottomup_level_1d(g: Dict[str, jax.Array], pi: jax.Array,
     instr = args.instrument
     ctr = zero_counters() if instr else {}
 
-    f_words, wire = expand_frontier_1d(front, args.axis)
+    with jax.named_scope(EXPAND):
+        f_words, wire = expand_frontier_1d(front, args.axis)
     if instr:
         ctr["wire_expand"] = wire
         ctr["use_expand"] = jnp.float32(
             comm_model.expand_1d_level_words(part.n, part.p))
 
-    cvec = (pi != -1).astype(jnp.int32)
-    ve = g["edge_dst"] if args.use_edge_dst and "edge_dst" in g else None
-    seg_par = _resolve_ops(args).bottomup(g["row_ptr"], g["col_idx"],
-                                          f_words, cvec, jnp.int32(0),
-                                          g["nnz"], ve, args)
-    newly = (pi == -1) & (seg_par != INT_INF)
-    pi = jnp.where(newly, seg_par, pi)
+    with jax.named_scope(DISCOVER):
+        cvec = (pi != -1).astype(jnp.int32)
+        ve = g["edge_dst"] if args.use_edge_dst and "edge_dst" in g \
+            else None
+        seg_par = _resolve_ops(args).bottomup(g["row_ptr"], g["col_idx"],
+                                              f_words, cvec, jnp.int32(0),
+                                              g["nnz"], ve, args)
+    with jax.named_scope(UPDATE):
+        newly = (pi == -1) & (seg_par != INT_INF)
+        pi = jnp.where(newly, seg_par, pi)
 
     if instr:
         row_lens = (g["row_ptr"][1:] - g["row_ptr"][:-1]).astype(jnp.float32)

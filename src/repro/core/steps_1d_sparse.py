@@ -75,6 +75,7 @@ from jax import lax
 from repro.core import comm_model
 from repro.core.frontier import (INT_INF, pack_bits, pack_ids, unpack_bits,
                                  unpack_ids)
+from repro.core.scopes import DISCOVER, EXPAND, FOLD
 from repro.core.steps import zero_counters
 from repro.core.steps_1d import (bottomup_level_1d, _resolve_ops,
                                  pipelined_expand_consume)
@@ -96,7 +97,7 @@ class LevelArgs1DS(NamedTuple):
     cap_f: int = 0            # kernel csr: frontier capacity (0 = n)
     maxdeg: int = 0           # kernel mode: max column-segment length
     ops: "object" = None      # LocalOps entry (None = look up from strings)
-    instrument: bool = True   # False: compile out counters/level_stats
+    instrument: bool = True   # False: compile out the counters
     codec: str = "none"       # sparse-bucket encoding: "none" | "packed"
     # software-pipelined expand: C sub-range bucket exchanges per level,
     # each consumed while the next is in flight (1 = classic schedule);
@@ -330,17 +331,18 @@ def topdown_level_1ds(g: Dict[str, jax.Array], pi: jax.Array,
     else:
         # --- Expand: owner-directed sparse ids, dense bitmap on
         # overflow --
-        f_words, wire, _ = sparse_exchange_1d(
-            front, args.axis, args.cap_x, part, over=over,
-            instrument=instr, visited=visited, codec=args.codec,
-            use_kernel=(args.local_mode == "kernel"),
-            interpret=args.interpret)
-        f_all = unpack_bits(f_words)                 # (n,) bool
+        with jax.named_scope(EXPAND):
+            f_words, wire, _ = sparse_exchange_1d(
+                front, args.axis, args.cap_x, part, over=over,
+                instrument=instr, visited=visited, codec=args.codec,
+                use_kernel=(args.local_mode == "kernel"),
+                interpret=args.interpret)
+            f_all = unpack_bits(f_words)             # (n,) bool
         # --- Local discovery: unchanged from "1d" (same LocalOps
         # entries) --
-        cand, ex_local = _resolve_ops(args).topdown(g, f_words, f_all,
-                                                    part.chunk,
-                                                    jnp.int32(0), args)
+        with jax.named_scope(DISCOVER):
+            cand, ex_local = _resolve_ops(args).topdown(
+                g, f_words, f_all, part.chunk, jnp.int32(0), args)
     if instr:
         ctr["wire_expand"] = wire
         n_f = lax.psum(jnp.sum(front, dtype=jnp.float32), args.axis)
@@ -352,8 +354,9 @@ def topdown_level_1ds(g: Dict[str, jax.Array], pi: jax.Array,
             args.axis)
 
     # --- Local update (children are owned; no fold) ----------------------
-    newly = (pi == -1) & (cand != INT_INF)
-    pi = jnp.where(newly, cand, pi)
+    with jax.named_scope(FOLD):
+        newly = (pi == -1) & (cand != INT_INF)
+        pi = jnp.where(newly, cand, pi)
     return pi, newly, ctr
 
 

@@ -44,7 +44,7 @@ from __future__ import annotations
 
 import time
 from dataclasses import dataclass
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -164,6 +164,16 @@ def _first_occurrence(cu, nnz, n_sentinel: int, cap_nz: int):
     return jc, cp, nzc, maxdeg
 
 
+def _aot(fn, *args, spent: List[float]):
+    """``fn`` lowered and compiled ahead of time (or loaded from the
+    persistent compilation cache), the seconds it took appended to
+    ``spent`` — so a build reports its compile apart from its run."""
+    t0 = time.perf_counter()
+    compiled = fn.lower(*args).compile()
+    spent.append(time.perf_counter() - t0)
+    return compiled
+
+
 def _scatter_front(vals, nnz, cap: int, fill: int = 0):
     """First ``nnz`` entries of ``vals`` into a (cap,) zero/fill-padded
     array (the host builders' zero-padded block rows)."""
@@ -180,14 +190,20 @@ def _scatter_front(vals, nnz, cap: int, fill: int = 0):
 def dist_build_1d(spec: BuildSpec, p: int, mesh, *, align: int = 128,
                   cap_pad: int = 128, route_slack: float = 1.5,
                   row_axis: str = ROW_AXIS,
+                  compile_spent: Optional[List[float]] = None,
                   ) -> Tuple[Blocked1DGraph, Dict[str, Any]]:
     """Device-side distributed build of the 1D row-strip format.
 
     Bit-identical to ``build_blocked_1d(rmat_graph(..., generator=
     "counter"), p, align, cap_pad)`` — same edge set, same sort orders,
     same capacity rounding — but no edge array ever exists on host:
-    only per-shard scalar stats cross the device boundary."""
+    only per-shard scalar stats cross the device boundary.
+
+    Each phase program compiles ahead of its run; the seconds go to
+    ``compile_spent`` (a fresh list when None), whose sum is
+    ``info["compile_s"]``."""
     spec.validate()
+    spent = [] if compile_spent is None else compile_spent
     part = make_partition_1d(spec.n, p, align)
     chunk, n_pad = part.chunk, part.n
     m_input = spec.m_input
@@ -235,7 +251,7 @@ def dist_build_1d(spec: BuildSpec, p: int, mesh, *, align: int = 128,
                                           P(row_axis), P(row_axis)),
                                check_vma=False))
     t0 = time.perf_counter()
-    cu_all, cv_all, deg_all, stats_all = p1()
+    cu_all, cv_all, deg_all, stats_all = _aot(p1, spent=spent)()
     stats = np.asarray(stats_all)                # (p, 5) scalars only
     t1 = time.perf_counter()
     if stats[:, 3].max() > 0:
@@ -275,7 +291,8 @@ def dist_build_1d(spec: BuildSpec, p: int, mesh, *, align: int = 128,
         out_specs=tuple(P(row_axis) for _ in range(10)),
         check_vma=False))
     (edge_src, row_idx, row_ptr, col_idx, edge_dst, jc, cp,
-     nnz_d, nzc_d, deg_A) = p2(cu_all, cv_all, deg_all)
+     nnz_d, nzc_d, deg_A) = _aot(p2, cu_all, cv_all, deg_all,
+                                 spent=spent)(cu_all, cv_all, deg_all)
     jax.block_until_ready(edge_src)
     t2 = time.perf_counter()
 
@@ -287,6 +304,7 @@ def dist_build_1d(spec: BuildSpec, p: int, mesh, *, align: int = 128,
         cap=cap, cap_nzc=cap_nzc, maxdeg_col=maxdeg_col, col_ptr=None)
     info = {
         "build_s": t2 - t0, "gen_route_s": t1 - t0, "format_s": t2 - t1,
+        "compile_s": sum(spent),
         "cap_route": cap_route, "m": m, "m_input": m_input,
         "build_teps": m_input / max(t2 - t0, 1e-12),
         "route_words_measured": float(stats[:, 4].sum()),
@@ -306,6 +324,7 @@ def dist_build_2d(spec: BuildSpec, pr: int, pc: int, mesh, *,
                   align: int = 128, cap_pad: int = 128,
                   route_slack: float = 1.5, row_axis: str = ROW_AXIS,
                   col_axis: str = COL_AXIS,
+                  compile_spent: Optional[List[float]] = None,
                   ) -> Tuple[BlockedGraph, Dict[str, Any]]:
     """Device-side distributed build of the 2D (pr x pc) checkerboard,
     bit-identical to ``build_blocked`` on the counter edge stream.
@@ -313,8 +332,10 @@ def dist_build_2d(spec: BuildSpec, pr: int, pc: int, mesh, *,
     Owner routing is TWO single-axis hops (column owner along "model",
     then row owner along "data") instead of one p-way exchange — each
     hop is the same capped-bucket all_to_all as the 1D build, and the
-    closed form is comm_model.build_route_2d_words."""
+    closed form is comm_model.build_route_2d_words.  Compile seconds
+    go to ``compile_spent`` as in ``dist_build_1d``."""
     spec.validate()
+    spent = [] if compile_spent is None else compile_spent
     part = make_partition(spec.n, pr, pc, align)
     nr, nc, chunk, p = part.nr, part.nc, part.chunk, part.p
     n_pad = part.n
@@ -385,7 +406,7 @@ def dist_build_2d(spec: BuildSpec, pr: int, pc: int, mesh, *,
                                out_specs=tuple(P(*axes) for _ in range(4)),
                                check_vma=False))
     t0 = time.perf_counter()
-    cu_all, cv_all, deg_all, stats_all = p1()
+    cu_all, cv_all, deg_all, stats_all = _aot(p1, spent=spent)()
     stats = np.asarray(stats_all).reshape(p, -1)
     t1 = time.perf_counter()
     if stats[:, 5].max() > 0:
@@ -435,8 +456,8 @@ def dist_build_2d(spec: BuildSpec, pr: int, pc: int, mesh, *,
         out_specs=tuple(P(*axes) for _ in range(15)),
         check_vma=False))
     (col_ptr, row_idx, edge_src, row_ptr, col_idx, edge_dst, seg_ptr,
-     jc, cp, jr, rp, nnz_d, nzc_d, nzr_d, deg_A) = p2(cu_all, cv_all,
-                                                      deg_all)
+     jc, cp, jr, rp, nnz_d, nzc_d, nzr_d, deg_A) = _aot(
+        p2, cu_all, cv_all, deg_all, spent=spent)(cu_all, cv_all, deg_all)
     jax.block_until_ready(row_idx)
     t2 = time.perf_counter()
 
@@ -449,6 +470,7 @@ def dist_build_2d(spec: BuildSpec, pr: int, pc: int, mesh, *,
         cap=cap, cap_seg=cap_seg, maxdeg_col=maxdeg_col)
     info = {
         "build_s": t2 - t0, "gen_route_s": t1 - t0, "format_s": t2 - t1,
+        "compile_s": sum(spent),
         "cap_route": (cap_r1, cap_r2), "m": m, "m_input": m_input,
         "build_teps": m_input / max(t2 - t0, 1e-12),
         "route_words_measured": float(stats[:, 6].sum()),
@@ -475,7 +497,8 @@ def dist_build(spec: BuildSpec, decomposition: str, mesh, grid,
     to a first-try build with the final slack: the edge stream is a
     pure function of (seed, edge index) and slack only sizes the
     exchange buckets.  Exhaustion re-raises with the full escalation
-    history attached."""
+    history attached.  ``info["compile_s"]`` sums the phase compiles
+    (or persistent-cache loads) of every attempt."""
     if isinstance(grid, int):
         grid = (grid, 1)
     elif len(grid) == 1:
@@ -490,9 +513,11 @@ def dist_build(spec: BuildSpec, decomposition: str, mesh, grid,
 
     slack = float(kw.pop("route_slack", 1.5))
     history = []
+    spent = []
     for attempt in range(1, max(1, max_attempts) + 1):
         try:
-            graph, info = build(route_slack=slack, **kw)
+            graph, info = build(route_slack=slack, compile_spent=spent,
+                                **kw)
         except CapacityOverflow as e:
             history.append(RetryAttempt(
                 attempt=attempt, cap_name="route_slack", cap_value=slack,

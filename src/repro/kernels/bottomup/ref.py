@@ -7,9 +7,11 @@ segment's newly-discovered parents (global source ids; INT_INF = none).
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.frontier import INT_INF, test_bits
+from repro.core.scopes import EDGE_ROWS
 
 
 def bottomup_substep(rp_seg: jnp.ndarray,   # (chunk+1,) i32, rebased to window
@@ -27,13 +29,14 @@ def bottomup_substep(rp_seg: jnp.ndarray,   # (chunk+1,) i32, rebased to window
     cap = ue_win.shape[0]
     eidx = jnp.arange(cap, dtype=jnp.int32)
     valid = eidx < n_edges
-    if ve_win is None:
-        # row of each window edge (CSR order => rows nondecreasing)
-        erow = jnp.searchsorted(rp_seg, eidx,
-                                side="right").astype(jnp.int32) - 1
-        erow = jnp.clip(erow, 0, chunk - 1)
-    else:
-        erow = jnp.clip(ve_win, 0, chunk - 1)
+    with jax.named_scope(EDGE_ROWS):
+        if ve_win is None:
+            # row of each window edge (CSR order => rows nondecreasing)
+            erow = jnp.searchsorted(rp_seg, eidx,
+                                    side="right").astype(jnp.int32) - 1
+            erow = jnp.clip(erow, 0, chunk - 1)
+        else:
+            erow = jnp.clip(ve_win, 0, chunk - 1)
     notdone = (cvec == 0)[erow]
     in_frontier = test_bits(f_words, ue_win)
     hit = valid & notdone & in_frontier
