@@ -231,7 +231,6 @@ class BFSConfig:
     # paper's depth/time/TEPS runs use; its level_stats keep n_f, m_f,
     # mode and used (NaN expand words).  Parents are identical either way.
     instrument: bool = True
-    use_edge_dst: bool = False    # bottom-up O(E) row read (no searchsorted)
     compact_updates: bool = False  # bottom-up compact (child,parent) sends
     # "1ds" sparse-bucket encoding: "packed" bit-packs local offsets at
     # codec_bits(chunk) bits each behind a count word (~3x fewer bucket

@@ -9,18 +9,18 @@ CONFIG_TOPDOWN = register(dataclasses.replace(
     CONFIG, arch="bfs-rmat-topdown", direction_optimizing=False))
 
 # --- §Perf hillclimb variants (beyond-paper; see EXPERIMENTS.md §Perf) ---
-# i1: compact bitmap fold; i2: + O(E) edge-row reads; opt: + compact
-# parent updates.  *_pure folds are the steady-state path the roofline
-# lowers; the runtime config (bfs-rmat-opt-rt) keeps capacity fallbacks.
+# i1, i2: compact bitmap fold; opt: + compact parent updates.  *_pure
+# folds are the steady-state path the roofline lowers; the runtime config
+# (bfs-rmat-opt-rt) keeps capacity fallbacks.
 CONFIG_I1 = register(dataclasses.replace(
     CONFIG, arch="bfs-rmat-i1", fold_mode="bitmap_pure"))
 CONFIG_I2 = register(dataclasses.replace(
-    CONFIG, arch="bfs-rmat-i2", fold_mode="bitmap_pure", use_edge_dst=True))
+    CONFIG, arch="bfs-rmat-i2", fold_mode="bitmap_pure"))
 CONFIG_OPT = register(dataclasses.replace(
-    CONFIG, arch="bfs-rmat-opt", fold_mode="bitmap_pure", use_edge_dst=True,
+    CONFIG, arch="bfs-rmat-opt", fold_mode="bitmap_pure",
     compact_updates=True))
 CONFIG_OPT_RT = register(dataclasses.replace(
-    CONFIG, arch="bfs-rmat-opt-rt", fold_mode="bitmap", use_edge_dst=True,
+    CONFIG, arch="bfs-rmat-opt-rt", fold_mode="bitmap",
     compact_updates=True))
 # batched roots sharded over the pod axis (multi-pod Graph500 pattern)
 CONFIG_MULTIROOT = register(dataclasses.replace(

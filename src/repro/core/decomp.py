@@ -434,7 +434,6 @@ def _make_args_2d(part, cfg, ops, axes, statics: PlanStatics) -> LevelArgs:
                      cap_seg=statics.cap_seg,
                      local_mode=ops.local_mode, storage=cfg.storage,
                      cap_f=statics.cap_f, maxdeg=statics.maxdeg,
-                     use_edge_dst=cfg.use_edge_dst,
                      compact_updates=cfg.compact_updates, ops=ops,
                      instrument=statics.instrument,
                      expand_chunks=statics.expand_chunks,
@@ -529,7 +528,6 @@ _bfs_body_1d = _make_strip_body(topdown_level_1d, bottomup_level_1d)
 
 def _make_args_1d(part, cfg, ops, axes, statics: PlanStatics) -> LevelArgs1D:
     return LevelArgs1D(part=part, axis=axes[0],
-                       use_edge_dst=cfg.use_edge_dst,
                        local_mode=ops.local_mode, storage=cfg.storage,
                        cap_f=statics.cap_f, maxdeg=statics.maxdeg, ops=ops,
                        instrument=statics.instrument,
@@ -579,7 +577,6 @@ _bfs_body_1ds = _make_strip_body(topdown_level_1ds, bottomup_level_1ds)
 def _make_args_1ds(part, cfg, ops, axes,
                    statics: PlanStatics) -> LevelArgs1DS:
     return LevelArgs1DS(part=part, axis=axes[0], cap_x=statics.cap_x,
-                        use_edge_dst=cfg.use_edge_dst,
                         local_mode=ops.local_mode, storage=cfg.storage,
                         cap_f=statics.cap_f, maxdeg=statics.maxdeg, ops=ops,
                         instrument=statics.instrument,

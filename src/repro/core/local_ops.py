@@ -37,6 +37,9 @@ Closure signatures (all arrays squeezed to the local block/strip):
            args)
       -> (chunk,) i32 newly discovered parents (INT_INF = none)
 
+``ve_win`` is each window edge's row: the dense steps read it from the
+shipped CSR ``edge_dst`` (rebased to the segment in 2d); entries that
+ship no ``edge_dst`` get None, and the Pallas scan finds rows itself.
 ``f_words`` is the packed frontier bitmap over the block's column range
 (uint32 words), ``f_mask`` its unpacked bool form; 2D passes the C_j
 slice with col_offset = j*nc, 1D passes the full allgathered frontier

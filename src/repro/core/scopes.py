@@ -7,7 +7,7 @@ Every level runs under one top scope, its phases nested below it:
   bfs.bottomup   expand (the frontier gather and, in 2d, the ring
                  rotation of the completed bitmap), discover (the local
                  scan, with edge_rows nested around its per-edge row
-                 lookup), update
+                 read), update
   bfs.reduce     the loop's per-level reduction, the direction decision
                  and the level_stats row
 

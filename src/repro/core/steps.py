@@ -25,7 +25,7 @@ from jax import lax
 from repro.core import comm_model
 from repro.core.frontier import (INT_INF, expand_bitmap, pack_bits,
                                  unpack_bits)
-from repro.core.scopes import DISCOVER, EXPAND, FOLD, UPDATE
+from repro.core.scopes import DISCOVER, EDGE_ROWS, EXPAND, FOLD, UPDATE
 
 COUNTER_KEYS = ("wire_transpose", "wire_expand", "wire_fold", "wire_rotate",
                 "wire_updates", "use_expand", "use_fold", "use_rotate",
@@ -49,7 +49,6 @@ class LevelArgs(NamedTuple):
     cap_f: int = 0            # kernel mode: frontier capacity (0 = nc)
     maxdeg: int = 0           # kernel mode: max column-segment length
     cap_w: int = 0            # bitmap fold: winner capacity (0 = chunk//16)
-    use_edge_dst: bool = False  # bottom-up: read per-edge rows (no search)
     compact_updates: bool = False  # bottom-up: compact (child,parent) sends
     cap_u: int = 0            # compact updates capacity (0 = chunk//8)
     ops: "object" = None      # LocalOps entry (None = look up from strings)
@@ -377,9 +376,13 @@ def bottomup_level(g: Dict[str, jax.Array], pi: jax.Array, front: jax.Array,
             ue = lax.dynamic_slice_in_dim(g["col_idx"], e0, args.cap_seg)
             n_edges = (e1 - e0).astype(jnp.int32)
             cvec = cseg.astype(jnp.int32)
-            ve = (lax.dynamic_slice_in_dim(g["edge_dst"], e0, args.cap_seg)
-                  - seg_id * chunk) if args.use_edge_dst and "edge_dst" in g \
-                else None
+            ve = None             # kernel entries find rows inside the scan
+            if "edge_dst" in g:
+                with jax.named_scope(EDGE_ROWS):
+                    # each window edge's row, rebased to this segment
+                    ve = (lax.dynamic_slice_in_dim(g["edge_dst"], e0,
+                                                   args.cap_seg)
+                          - seg_id * chunk)
             seg_par = ops.bottomup(rp_seg, ue, f_words, cvec, col_offset,
                                    n_edges, ve, args)
             found = seg_par != INT_INF

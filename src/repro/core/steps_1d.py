@@ -47,7 +47,6 @@ class LevelArgs1D(NamedTuple):
     kernel all plug in behind the same two closures."""
     part: "object"            # Partition1D (static)
     axis: str                 # the single mesh axis name
-    use_edge_dst: bool = False  # bottom-up: read per-edge rows (no search)
     local_mode: str = "dense"  # "dense" | "kernel" (Pallas)
     storage: str = "csr"      # "csr" | "dcsc" (strip pointer compression)
     cap_f: int = 0            # kernel csr: frontier capacity (0 = n)
@@ -231,8 +230,9 @@ def bottomup_level_1d(g: Dict[str, jax.Array], pi: jax.Array,
 
     with jax.named_scope(DISCOVER):
         cvec = (pi != -1).astype(jnp.int32)
-        ve = g["edge_dst"] if args.use_edge_dst and "edge_dst" in g \
-            else None
+        # dense entries ship each edge's strip-local row; kernel entries
+        # ship none and find rows inside the scan
+        ve = g.get("edge_dst")
         seg_par = _resolve_ops(args).bottomup(g["row_ptr"], g["col_idx"],
                                               f_words, cvec, jnp.int32(0),
                                               g["nnz"], ve, args)

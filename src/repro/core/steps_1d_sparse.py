@@ -91,7 +91,6 @@ class LevelArgs1DS(NamedTuple):
     part: "object"            # Partition1D (static)
     axis: str                 # the single mesh axis name
     cap_x: int                # sparse exchange: ids per send bucket
-    use_edge_dst: bool = False  # bottom-up: read per-edge rows (no search)
     local_mode: str = "dense"  # "dense" | "kernel" (Pallas)
     storage: str = "csr"      # "csr" | "dcsc" (strip pointer compression)
     cap_f: int = 0            # kernel csr: frontier capacity (0 = n)

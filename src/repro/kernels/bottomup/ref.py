@@ -22,9 +22,12 @@ def bottomup_substep(rp_seg: jnp.ndarray,   # (chunk+1,) i32, rebased to window
                      n_edges: jnp.ndarray,     # scalar i32: window edge count
                      ve_win=None,           # (cap_seg,) i32 per-edge row - row0
                      ) -> jnp.ndarray:
-    """ve_win (precomputed per-edge local rows, the CSR edge_dst array)
-    replaces the O(E log V) searchsorted with a direct O(E) read — the
-    §Perf BFS memory-term optimization (iteration 2)."""
+    """``ve_win`` holds each window edge's row (the shipped CSR
+    ``edge_dst``, rebased to the segment): the dense steps always pass it,
+    an O(E) read.  Without it the rows come from an O(E log chunk)
+    ``searchsorted`` over ``rp_seg``; that branch serves only as the
+    oracle of the Pallas scan (kernels/bottomup/ops.py), which takes no
+    rows."""
     chunk = rp_seg.shape[0] - 1
     cap = ue_win.shape[0]
     eidx = jnp.arange(cap, dtype=jnp.int32)

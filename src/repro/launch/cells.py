@@ -412,7 +412,6 @@ def build_bfs_cell(cfg: BFSConfig, shape: BFSShape, mesh,
             part=part, row_axis="data", col_axis="model",
             fold_mode=cfg.fold_mode, perm=tuple(part.transpose_perm()),
             cap_seg=cap_seg, storage=cfg.storage,
-            use_edge_dst=cfg.use_edge_dst,
             compact_updates=cfg.compact_updates, ops=ops)
         keys = ops.keys
 

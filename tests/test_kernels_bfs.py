@@ -145,3 +145,35 @@ def test_bottomup_kernel_property(seed):
     assert (out[cvec == 1] == INT_INF).all()
     disc = np.flatnonzero(out != INT_INF)
     assert all(f[out[d]] for d in disc)
+
+
+@pytest.mark.parametrize("chunk,max_deg,tail", [
+    (32, 0, 128),     # every row empty: nothing in the window is live
+    (64, 9, 96),      # padded tail: n_edges < cap
+    (1, 40, 24),      # a single-row chunk
+    (128, 7, 0),      # the window is full: n_edges == cap
+], ids=["empty-rows", "padded-tail", "single-row", "full-window"])
+def test_bottomup_ref_rows_read_matches_search(chunk, max_deg, tail):
+    """The dense steps hand the oracle each window edge's row (edge_dst
+    rebased to the segment) instead of letting it search rp_seg: both
+    give the same parents.  The tail past n_edges holds rows of the next
+    segment, as the 2d window slice reads them."""
+    rng = np.random.default_rng(chunk + max_deg + tail)
+    nc = 64
+    deg = rng.integers(0, max_deg + 1, chunk)
+    rp = np.zeros(chunk + 1, np.int32)
+    rp[1:] = np.cumsum(deg)
+    n_edges = int(rp[-1])
+    cap = n_edges + tail
+    ue = rng.integers(0, nc, cap).astype(np.int32)
+    rows = np.concatenate([np.repeat(np.arange(chunk), deg),
+                           chunk + rng.integers(0, chunk, tail)])
+    f_words = pack_bits(jnp.asarray(rng.random(nc) < 0.4))
+    cvec = (rng.random(chunk) < 0.3).astype(np.int32)
+    args = (jnp.asarray(rp), jnp.asarray(ue), f_words, jnp.asarray(cvec),
+            jnp.int32(3 * nc), jnp.int32(n_edges))
+    want = np.asarray(bu_ref(*args))
+    got = np.asarray(bu_ref(*args, ve_win=jnp.asarray(rows, jnp.int32)))
+    np.testing.assert_array_equal(got, want)
+    if n_edges:
+        assert (want != INT_INF).any()

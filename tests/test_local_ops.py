@@ -1,6 +1,11 @@
 """LocalOps registry: the (decomposition, local_mode, storage) parity
 matrix, strip-DCSC builder invariants, and the §5.1 storage accounting
 for the 1D strip formats."""
+import json
+import os
+import subprocess
+import sys
+
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
@@ -90,6 +95,101 @@ def test_parity_matrix(fixed_graph):
         kern = [c for c in group if c[1] == "kernel"]
         assert (res[kern[0]].counters["edges_examined"]
                 == pytest.approx(res[kern[1]].counters["edges_examined"]))
+
+
+# Every dense combo on 4 forced host devices: 2d on 2x2 and 1x4 meshes
+# (pc > 1, so a bottom-up sub-step reads segments other than its own and
+# rebases edge_dst by seg_id * chunk), 1d/1ds on 4 strips.  Each combo
+# runs twice: as registered (rows read from edge_dst) and with its
+# bottom-up closure forced onto the oracle's rp_seg search.
+_DENSE_MESH_MAIN = """
+import dataclasses, json, os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import numpy as np
+from repro.configs.base import BFSConfig
+from repro.core import local_ops
+from repro.core.engine import plan_bfs
+from repro.core.ref import bfs_depths, depths_from_parents, validate_parents
+from repro.graph.formats import build_blocked, build_blocked_1d
+from repro.graph.rmat import rmat_graph
+from repro.launch.mesh import make_local_mesh, make_local_mesh_1d
+
+def searching(rp_seg, ue_win, f_words, cvec, col_offset, n_edges, ve_win,
+              args):
+    return local_ops._bu_ref(rp_seg, ue_win, f_words, cvec, col_offset,
+                             n_edges, None, args)
+
+def run(graph, cfg, mesh, roots):
+    eng = plan_bfs(graph, cfg, mesh).compile()
+    return [eng.run(int(r)) for r in roots]
+
+e = rmat_graph(9, edge_factor=8, seed=3)
+roots = np.flatnonzero(e.out_degrees())[[0, 7, 41]]
+g1 = build_blocked_1d(e, 4, align=32, cap_pad=32)
+out = {}
+for dc, lm, st in local_ops.registered_combos():
+    if lm != "dense":
+        continue
+    meshes = ([("2x2", build_blocked(e, 2, 2, align=32, cap_pad=32),
+                make_local_mesh(2, 2)),
+               ("1x4", build_blocked(e, 1, 4, align=32, cap_pad=32),
+                make_local_mesh(1, 4))] if dc == "2d"
+              else [("p4", g1, make_local_mesh_1d(4))])
+    ops = local_ops.get_local_ops(dc, lm, st)
+    cfg = BFSConfig(decomposition=dc, storage=st, instrument=False)
+    rows = []
+    for name, g, mesh in meshes:
+        got = run(g, cfg, mesh, roots)
+        local_ops.unregister_local_ops(dc, lm, st)
+        local_ops.register_local_ops(dataclasses.replace(
+            ops, bottomup=searching))
+        try:
+            want = run(g, cfg, mesh, roots)
+        finally:
+            local_ops.unregister_local_ops(dc, lm, st)
+            local_ops.register_local_ops(ops)
+        for r, a, b in zip(roots, got, want):
+            ok, msg = validate_parents(e.n, e.src, e.dst, int(r), a.parents)
+            rows.append(dict(
+                mesh=name, root=int(r), valid=bool(ok), msg=msg,
+                depths=bool(np.array_equal(
+                    depths_from_parents(e.n, a.parents, int(r)),
+                    bfs_depths(e.n, e.src, e.dst, int(r)))),
+                same=bool(np.array_equal(a.parents, b.parents)),
+                bu_levels=int((a.level_stats[:, 2] == 1).sum())))
+    out["-".join((dc, lm, st))] = rows
+print(json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def dense_mesh_runs():
+    env = dict(os.environ)
+    env.pop("XLA_FLAGS", None)
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["PYTHONPATH"] = os.pathsep.join(
+        [src] + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
+    r = subprocess.run([sys.executable, "-c", _DENSE_MESH_MAIN],
+                       capture_output=True, text=True, timeout=600, env=env)
+    assert r.returncode == 0, r.stderr
+    return json.loads(r.stdout.splitlines()[-1])
+
+
+@pytest.mark.parametrize("combo", [
+    "-".join(c) for c in local_ops.registered_combos() if c[1] == "dense"])
+def test_dense_parity_on_multi_device_mesh(dense_mesh_runs, combo):
+    """Dense bottom-up discovery reads each edge's row from edge_dst; on
+    a mesh with more than one column (2d) or strip (1d/1ds) its trees
+    hold to the oracle and equal, bit for bit, those of the rp_seg
+    search, with bottom-up levels run."""
+    rows = dense_mesh_runs[combo]
+    assert rows
+    for row in rows:
+        assert row["valid"], (combo, row)
+        assert row["depths"] and row["same"], (combo, row)
+    for mesh in {row["mesh"] for row in rows}:
+        assert sum(row["bu_levels"] for row in rows
+                   if row["mesh"] == mesh) > 0, (combo, mesh)
 
 
 def test_multiroot_routes_through_registry():

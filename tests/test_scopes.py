@@ -100,6 +100,24 @@ def test_dense_bottomup_names_its_row_lookup(fixed_graph):
     assert "bfs.bottomup/discover/edge_rows" in set(eng.op_scopes().values())
 
 
+@pytest.mark.parametrize("combo", [c for c in local_ops.registered_combos()
+                                   if c[1] == "dense"],
+                         ids=lambda c: "-".join(c))
+def test_dense_bottomup_reads_rows_without_searching(fixed_graph, combo):
+    """Dense bottom-up discovery reads each edge's row from the shipped
+    edge_dst: its row lookup compiles to no loop, and no while of the
+    timed search lies under a level-program scope (the level loop
+    itself lies outside them)."""
+    dc, lm, st = combo
+    found = _plan(fixed_graph, dc, lm, st,
+                  instrument=False).compile().op_scopes()
+    rows = f"{scopes.BOTTOMUP}/{scopes.DISCOVER}/{scopes.EDGE_ROWS}"
+    assert rows in set(found.values()), combo
+    loops = sorted((name, scope) for name, scope in found.items()
+                   if name.startswith("while"))
+    assert not loops, (combo, loops)
+
+
 @pytest.mark.parametrize("dc", ["1d", "1ds", "2d"])
 def test_fast_batch_level_stats_match_single_runs(fixed_graph, dc):
     """Pod-batched fast searches carry each root's own level_stats:

@@ -12,6 +12,7 @@ import time: only one process at a time may load the TPU library, and
 every test worker imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,7 +22,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs.base import BFSConfig
-from repro.core.engine import plan_bfs
+from repro.core.engine import hlo_op_scopes, plan_bfs
 from repro.graph.formats import build_blocked, build_blocked_1d
 from repro.graph.rmat import rmat_graph
 from repro.kernels.bottomup.bottomup import bottomup_substep_kernel
@@ -156,12 +157,9 @@ def test_plan_on_described_tpu_mesh_compiles_kernels(topo, decomposition):
     assert not plan.statics.interpret and not plan.level_args().interpret
 
 
-@pytest.mark.parametrize("local_mode", ["dense", "kernel"])
-@pytest.mark.parametrize("decomposition", ["1d", "1ds", "2d"])
-def test_whole_search_compiles(topo, decomposition, local_mode):
-    """The single-root search program of each decomposition compiles
-    for one v5e chip; kernel mode carries the Mosaic kernels
-    (``tpu_custom_call``), dense mode is plain XLA."""
+def _search_hlo(topo, decomposition, local_mode):
+    """The compiled single-root (instrument=False) search for one
+    described v5e chip, as HLO text."""
     g = _small_graph(decomposition)
     mesh = _topo_mesh(topo, decomposition)
     cfg = BFSConfig(decomposition=decomposition, storage="dcsc",
@@ -174,5 +172,25 @@ def test_whole_search_compiles(topo, decomposition, local_mode):
                                      sharding=sh) for k in plan.keys}
     root = jax.ShapeDtypeStruct((), jnp.int32,
                                 sharding=NamedSharding(mesh, P()))
-    hlo = plan.build_fn().lower(gspec, root).compile().as_text()
+    return plan.build_fn().lower(gspec, root).compile().as_text()
+
+
+@pytest.mark.parametrize("local_mode", ["dense", "kernel"])
+@pytest.mark.parametrize("decomposition", ["1d", "1ds", "2d"])
+def test_whole_search_compiles(topo, decomposition, local_mode):
+    """The single-root search program of each decomposition compiles
+    for one v5e chip; kernel mode carries the Mosaic kernels
+    (``tpu_custom_call``), dense mode is plain XLA."""
+    hlo = _search_hlo(topo, decomposition, local_mode)
     assert ("tpu_custom_call" in hlo) == (local_mode == "kernel")
+
+
+def test_dense_one_chip_search_has_only_the_level_loop(topo):
+    """The dense 2d search on one chip reads each edge's row from
+    edge_dst: the level loop is its one while, and no loop lies under
+    the bottom-up row lookup's scope."""
+    hlo = _search_hlo(topo, "2d", "dense")
+    loops = re.findall(r"^\s*(?:ROOT\s+)?%?(while[\w.-]*)\s*=", hlo,
+                       re.MULTILINE)
+    assert len(loops) == 1, loops
+    assert loops[0] not in hlo_op_scopes(hlo)
