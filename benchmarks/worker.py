@@ -78,7 +78,8 @@ def _build_store_phase(payload):
 
     # born-sharded graphs have no host edge list: pick high-degree roots
     # from the (small) degree vector instead of random_source(edges)
-    deg = np.asarray(g.deg_A).ravel()         # layout A ravel == global id
+    from repro.graph.formats import input_degrees
+    deg = input_degrees(g)
     roots = np.argsort(deg)[::-1][: payload.get("roots", 4)]
     t3 = time.perf_counter()
     out0 = eng.search(int(roots[0]))

@@ -77,7 +77,8 @@ def _build(spec, decomposition, chips):
 
 
 def _pick_roots(graph):
-    deg = np.asarray(graph.deg_A).reshape(-1)[: graph.part.n_orig]
+    from repro.graph.formats import input_degrees
+    deg = input_degrees(graph)
     rng = np.random.default_rng(ROOT_SEED)
     return [int(r) for r in rng.choice(np.flatnonzero(deg > 0), N_ROOTS,
                                        replace=False)]

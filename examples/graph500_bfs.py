@@ -138,7 +138,8 @@ def main():
     # vector instead of random_source(edges)
     deg_global = None
     if edges is None:
-        deg_global = np.flatnonzero(np.asarray(graph.deg_A).ravel() > 0)
+        from repro.graph.formats import input_degrees
+        deg_global = np.flatnonzero(input_degrees(graph) > 0)
     rates, res = [], None
     for i in range(args.roots):
         root = int(rng.choice(deg_global)) if edges is None \

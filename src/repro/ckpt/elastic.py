@@ -47,8 +47,9 @@ def repartition_graph(edges: "EdgeList | None" = None, pr: int = 1,
       ("2d" checkerboard, "1d"/"1ds" strips on pr*pc devices), and
       extra ``build_kw`` (route_slack, max_attempts, ...) flow through
       to ``dist_build``.  Bit-identical to a host re-block of the same
-      stream at matching align/cap_pad (test_faultinject pins p=1
-      parity).
+      stream at matching align/cap_pad, relabeled on more than one
+      shard as ``dist_build.build_relabel`` says (test_faultinject pins
+      p=1 parity, test_dist_build p=4).
     """
     if spec is not None:
         if mesh is None:

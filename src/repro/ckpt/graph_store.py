@@ -58,6 +58,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.ckpt import checkpoint
 from repro.core.partition import Partition1D, Partition2D
 from repro.graph.formats import Blocked1DGraph, BlockedGraph
+from repro.graph.rmat import VertexRelabel
 
 try:
     from jax.experimental import serialize_executable as _serialize_exec
@@ -193,6 +194,8 @@ class GraphStore:
             "fields": json.dumps(
                 {k: [list(v.shape), str(v.dtype)]
                  for k, v in sorted(arrays.items())}),
+            "relabel": None if graph.relabel is None
+            else asdict(graph.relabel),
             **({"spec_hash": checkpoint.config_hash(spec),
                 "spec": json.dumps(asdict(spec), sort_keys=True)}
                if is_dataclass(spec) and spec is not None else {}),
@@ -346,8 +349,13 @@ class GraphStore:
             sh = NamedSharding(mesh, P(*axes))
             arrays = {k: jax.device_put(v, sh) for k, v in arrays.items()}
         cls = _GRAPH_KINDS[meta["graph_kind"]]
+        rl = meta.get("relabel")
+        relabel = None if rl is None else VertexRelabel(
+            scale=rl["scale"], adds=tuple(rl["adds"]),
+            muls=tuple(rl["muls"]))
         return cls(part=part, m_input=meta["m_input"], m=meta["m"],
-                   **json.loads(meta["scalars"]), **arrays)
+                   relabel=relabel, **json.loads(meta["scalars"]),
+                   **arrays)
 
     # ------------------------------------------------------------------
     # executables

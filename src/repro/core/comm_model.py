@@ -344,26 +344,27 @@ def build_route_padded_words(p: int, cap_route: int) -> float:
     return float(p) * (p - 1) * cap_route
 
 
-def rmat_strip_skew(p: int, a: float = 0.57, b: float = 0.19) -> float:
-    """Expected fraction of R-MAT edge endpoints owned by the heaviest
-    1/p vertex range (the low-id strip): each of the log2(p) leading
-    quadrant draws lands in the top half with probability a+b, so strip
-    0 receives ~(a+b)**log2(p) of all endpoints — the factor a uniform
-    cap_route must be inflated by before skewed routing fits."""
-    import math
+def relabeled_route_share(p: int, scale: int, a: float = 0.57,
+                          b: float = 0.19) -> float:
+    """Expected fraction of the records the heaviest of p buckets takes
+    once the vertex ids are relabeled (graph/rmat.py ``VertexRelabel``,
+    which every build over more than one shard applies): the relabel
+    scatters R-MAT's low-id hubs, so a bucket takes a uniform 1/p plus
+    at most the heaviest vertex's share (a+b)**scale of the endpoints,
+    which no relabel can split.  One bucket takes every record."""
     if p <= 1:
         return 1.0
-    return float((a + b) ** math.log2(p))
+    return min(1.0, 1.0 / p + (a + b) ** scale)
 
 
-def plan_cap_route(records: int, p: int, a: float = 0.57, b: float = 0.19,
+def plan_cap_route(records: int, p: int, share: float,
                    slack: float = 1.5, pad: int = 32) -> int:
     """Static per-destination bucket capacity for one routing round:
     ``records`` locally generated records spread over p buckets whose
-    heaviest takes ~rmat_strip_skew(p), inflated by ``slack`` for
-    sampling noise.  Overflow is detected on device and raised loudly —
-    the build never silently drops an edge."""
-    frac = max(rmat_strip_skew(p, a, b), 1.0 / max(p, 1))
+    heaviest takes ``share`` of them (``relabeled_route_share``),
+    inflated by ``slack`` for sampling noise.  Overflow is detected on
+    device and raised loudly — the build never silently drops an edge."""
+    frac = max(share, 1.0 / max(p, 1))
     cap = int(slack * frac * records) + pad
     return ((cap + pad - 1) // pad) * pad
 

@@ -55,7 +55,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import BFSConfig
 from repro.core.partition import Partition1D, Partition2D
-from repro.core.scopes import BOTTOMUP, REDUCE, TOPDOWN
+from repro.core.scopes import BOTTOMUP, REDUCE, RELABEL, TOPDOWN
 from repro.core.steps import (COUNTER_KEYS, LevelArgs, bottomup_level,
                               topdown_level, zero_counters)
 from repro.core.steps_1d import (LevelArgs1D, bottomup_level_1d,
@@ -63,6 +63,7 @@ from repro.core.steps_1d import (LevelArgs1D, bottomup_level_1d,
 from repro.core.steps_1d_sparse import (LevelArgs1DS, bottomup_level_1ds,
                                         topdown_level_1ds)
 from repro.graph.formats import Blocked1DGraph, BlockedGraph
+from repro.graph.rmat import VertexRelabel
 
 MAX_LEVELS = 64
 
@@ -85,6 +86,9 @@ class PlanStatics:
     #                           fast path; parents identical)
     interpret: bool = False   # Pallas kernels in the interpreter: derived
     #                           from the plan's mesh (True only on CPU)
+    relabel: Optional[VertexRelabel] = None  # the graph's vertex relabel:
+    #                           the program takes the root and returns
+    #                           the parents in input labels (relabeled)
 
 
 @dataclass(frozen=True)
@@ -191,6 +195,37 @@ def unregister_decomposition(name: str) -> None:
     if name not in _REGISTRY:
         raise ValueError(f"no decomposition registered for {name!r}")
     del _REGISTRY[name]
+
+
+# ---------------------------------------------------------------------------
+# Input labels in and out of a relabeled graph
+# ---------------------------------------------------------------------------
+
+
+def relabeled(body, relabel: VertexRelabel, axes: Tuple[str, ...]):
+    """``body`` of any entry over a graph stored under the relabeled ids
+    ``h(x)``, taking the root and returning the parents in input labels:
+    the root goes in through ``h``; each device then all-gathers the
+    stored-label parents and, for the input labels x of its own layout-A
+    chunk, returns ``h^-1(pi[h(x)])`` (-1 stays -1).  Both maps run under
+    the ``bfs.relabel`` scope, outside the level loop."""
+
+    def run(g, root, **kw):
+        with jax.named_scope(RELABEL):
+            root = relabel.apply(root, jnp)
+        pi, level, ctr, stats = body(g, root, **kw)
+        with jax.named_scope(RELABEL):
+            loc = pi.reshape(-1)
+            full, k = loc, 0
+            for ax in reversed(axes):
+                full = lax.all_gather(full, ax, tiled=True)
+            for ax in axes:
+                k = k * lax.axis_size(ax) + lax.axis_index(ax)
+            x = k * loc.shape[0] + jnp.arange(loc.shape[0], dtype=jnp.int32)
+            out = relabel.invert(full[relabel.apply(x, jnp)], jnp)
+        return out.reshape(pi.shape), level, ctr, stats
+
+    return run
 
 
 # ---------------------------------------------------------------------------

@@ -48,7 +48,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs.base import BFSConfig
 from repro.core import comm_model
 from repro.core.decomp import (Decomposition, PlanStatics,
-                               get_decomposition)
+                               get_decomposition, relabeled)
 from repro.core.local_ops import LocalOps, get_local_ops
 from repro.core.scopes import op_scope
 
@@ -110,6 +110,16 @@ class BFSPlan:
         return self.entry.make_level_args(self.part, self.cfg, self.ops,
                                           self.axes, self.statics)
 
+    def _search_body(self, sync_axis: Optional[str]):
+        """The entry's whole-search body, in input labels on a
+        relabeled graph."""
+        body = functools.partial(self.entry.body, part=self.part,
+                                 args=self.level_args(), cfg=self.cfg,
+                                 sync_axis=sync_axis)
+        relabel = self.statics.relabel
+        return body if relabel is None else relabeled(body, relabel,
+                                                      self.axes)
+
     # ---- program builders -------------------------------------------------
 
     def build_fn(self, sync_axis: Optional[str] = None, trace_hook=None):
@@ -117,9 +127,7 @@ class BFSPlan:
         fn(graph_arrays_dict, root) -> (pi, level, ctr, stats).
         ``trace_hook`` (if given) is called once per jit trace — the
         engine uses it to assert compile-once behavior."""
-        body = functools.partial(self.entry.body, part=self.part,
-                                 args=self.level_args(), cfg=self.cfg,
-                                 sync_axis=sync_axis)
+        body = self._search_body(sync_axis)
         if trace_hook is not None:
             inner = body
 
@@ -147,9 +155,7 @@ class BFSPlan:
         if pod_axis not in self.mesh.shape:
             raise ValueError(f"mesh has no {pod_axis!r} axis for batched "
                              f"roots; axes are {tuple(self.mesh.shape)}")
-        body1 = functools.partial(self.entry.body, part=self.part,
-                                  args=self.level_args(), cfg=self.cfg,
-                                  sync_axis=pod_axis)
+        body1 = self._search_body(pod_axis)
         n_axes = self.entry.n_axes
 
         def multi_body(g, roots):
@@ -208,10 +214,11 @@ def plan_for_part(part, cfg: BFSConfig, mesh, *,
                   row_axis: str = "data", col_axis: str = "model",
                   local_mode: str = "dense", cap_seg: int = 0,
                   maxdeg: int = 0, cap_f: int = 0, cap_x: int = 0,
-                  n_real_edges: float = 0.0) -> BFSPlan:
+                  n_real_edges: float = 0.0, relabel=None) -> BFSPlan:
     """A graph-less plan from an explicit partition + static capacities
     (abstract lowering, compat builders).  Performs every validation
-    that does not need concrete arrays."""
+    that does not need concrete arrays.  ``relabel`` is the graph's
+    vertex relabel (``graph.relabel``; None for input labels)."""
     entry = get_decomposition(cfg.decomposition)
     if not isinstance(part, entry.partition_cls):
         raise TypeError(
@@ -246,7 +253,7 @@ def plan_for_part(part, cfg: BFSConfig, mesh, *,
                           cap_x=cap_x, n_real_edges=n_real_edges,
                           instrument=cfg.instrument,
                           expand_chunks=cfg.expand_chunks,
-                          interpret=interpret)
+                          interpret=interpret, relabel=relabel)
     entry.validate(part, statics)
     return BFSPlan(part=part, cfg=cfg, mesh=mesh, entry=entry, ops=ops,
                    axes=axes, statics=statics)
@@ -283,7 +290,7 @@ def plan_bfs(graph, cfg: BFSConfig, mesh, *,
         graph.part, cfg, mesh, row_axis=row_axis, col_axis=col_axis,
         local_mode=local_mode, cap_f=cap_f, cap_x=cap_x,
         cap_seg=getattr(graph, "cap_seg", 0), maxdeg=graph.maxdeg_col,
-        n_real_edges=float(graph.m))
+        n_real_edges=float(graph.m), relabel=graph.relabel)
     arrays = graph.device_arrays()
     missing = [k for k in plan.keys if k not in arrays]
     if missing:
@@ -447,12 +454,14 @@ class BFSEngine:
     def search(self, root: int):
         """Device-level search: (pi, level, ctr, stats) as device arrays,
         no host transfer.  Benchmark loops time this (+ a block on pi)
-        so per-root numbers measure traversal, not result conversion."""
+        so per-root numbers measure traversal, not result conversion.
+        The root and the parents are input labels: on a relabeled graph
+        the program itself maps them (``core/decomp.py::relabeled``)."""
         return self._exec(self._gdev, jnp.int32(self._check_root(root)))
 
     def to_result(self, out) -> BFSResult:
         """Convert a ``search`` output to the layout-independent
-        BFSResult (parents indexed by global vertex id, counters in the
+        BFSResult (parents indexed by input vertex label, counters in the
         shared COUNTER_KEYS units) so 1D and 2D runs diff directly."""
         part = self.plan.part
         pi, level, ctr, stats = out

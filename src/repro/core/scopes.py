@@ -11,6 +11,12 @@ Every level runs under one top scope, its phases nested below it:
   bfs.reduce     the loop's per-level reduction, the direction decision
                  and the level_stats row
 
+and, outside the level loop, on a graph stored under relabeled vertex
+ids (more than one shard, ``graph/dist_build.py``):
+
+  bfs.relabel    the root mapped in, and the parents gathered and
+                 mapped back to input labels
+
 The scopes are ``jax.named_scope`` metadata: each compiled instruction's
 ``op_name`` carries the path (through JAX's own ``while/body``,
 ``cond/branch_*`` components), and ``op_scope`` reads it back.  They add
@@ -24,13 +30,14 @@ from typing import Optional
 TOPDOWN = "bfs.topdown"
 BOTTOMUP = "bfs.bottomup"
 REDUCE = "bfs.reduce"
+RELABEL = "bfs.relabel"
 EXPAND = "expand"
 DISCOVER = "discover"
 FOLD = "fold"
 UPDATE = "update"
 EDGE_ROWS = "edge_rows"
 
-TOP_SCOPES = (TOPDOWN, BOTTOMUP, REDUCE)
+TOP_SCOPES = (TOPDOWN, BOTTOMUP, REDUCE, RELABEL)
 PHASES = (EXPAND, DISCOVER, FOLD, UPDATE, EDGE_ROWS)
 
 

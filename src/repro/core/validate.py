@@ -52,6 +52,10 @@ pass/fail plus per-check tallies, not a deduplicated edge list.
 
 Padded ghost vertices (ids in [n_orig, n)) have no edges and parent
 -1 in any legal run, so they can never contribute a violation.
+
+On a graph stored under relabeled ids (``graph.relabel``), the root and
+the parent array arrive in input labels, as the search returns them;
+the program maps both to the stored ids before the checks.
 """
 from __future__ import annotations
 
@@ -133,6 +137,7 @@ def build_validate_fn(plan):
     chunk = part.chunk
     n_axes = entry.n_axes
     squeeze = (0,) * n_axes
+    relabel = plan.statics.relabel
 
     def body(g, pi, root):
         g = {k: v[squeeze] for k, v in g.items()}
@@ -151,6 +156,12 @@ def build_validate_fn(plan):
         gidx = base + jnp.arange(chunk, dtype=jnp.int32)
 
         vid = jnp.arange(n, dtype=jnp.int32)
+        if relabel is not None:
+            # input labels -> the stored ids: stored vertex v is input
+            # h^-1(v), and its parent h(pi[h^-1(v)])
+            root = relabel.apply(root, jnp)
+            pi_all = relabel.apply(pi_all[relabel.invert(vid, jnp)], jnp)
+            pi_loc = lax.dynamic_slice(pi_all, (base,), (chunk,))
         in_tree = pi_all >= 0
         ok_ref = in_tree & (pi_all < n)      # parent is a usable index
         is_root = vid == root

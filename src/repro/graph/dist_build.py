@@ -8,7 +8,11 @@ materializes the edge list.  This module builds ``Blocked1DGraph`` /
   1. **generate** — each device draws its slice [k*m/p, (k+1)*m/p) of
      the counter-based R-MAT stream (graph/rmat.py): the stream is a
      pure function of (seed, edge index), so the union of shard slices
-     is bit-identical for every device count p.
+     is bit-identical for every device count p.  On more than one
+     shard both endpoints are relabeled by the seeded bijection of
+     ``build_relabel``, which spreads R-MAT's low-id hubs over the
+     shards; the graph carries it, and the search maps roots in and
+     parents out through it, so callers see input labels only.
   2. **owner-route** — every edge is emitted in both directions
      (symmetrization before routing) and shipped to the owner of its
      destination vertex with the same capped-bucket tiled
@@ -55,7 +59,8 @@ from jax.sharding import PartitionSpec as P
 from repro.core import comm_model
 from repro.core.partition import make_partition, make_partition_1d
 from repro.graph.formats import Blocked1DGraph, BlockedGraph, _round_up
-from repro.graph.rmat import rmat_edges_counter, rmat_edges_counter_jax
+from repro.graph.rmat import (VertexRelabel, rmat_edges_counter,
+                              rmat_edges_counter_jax)
 from repro.launch.mesh import COL_AXIS, ROW_AXIS
 from repro.runtime.retry import CapacityOverflow, RetryAttempt
 
@@ -88,6 +93,32 @@ class BuildSpec:
         if self.m_input >= 1 << 32:
             raise ValueError(f"m_input={self.m_input} exhausts the uint32 "
                              f"counter space")
+
+
+def build_relabel(spec: BuildSpec, p: int) -> Optional[VertexRelabel]:
+    """The vertex relabel a build over ``p`` shards applies: the seeded
+    bijection of ``graph/rmat.py`` when p > 1, where it balances the
+    shards' edges, and none on one shard, which has nothing to
+    balance."""
+    return VertexRelabel.from_seed(spec.scale, spec.seed) if p > 1 else None
+
+
+def route_caps_2d(spec: BuildSpec, pr: int, pc: int,
+                  route_slack: float = 1.5) -> Tuple[int, int]:
+    """(cap_r1, cap_r2): the bucket capacities of the 2D build's two
+    routing hops, per destination and per device."""
+    nrec = 2 * -(-spec.m_input // (pr * pc))
+    share_c, share_r = (comm_model.relabeled_route_share(
+        q, spec.scale, spec.a, spec.b) for q in (pc, pr))
+    cap_r1 = comm_model.plan_cap_route(nrec, pc, share_c,
+                                       slack=route_slack)
+    # hop 2 buckets the whole column's records by block row: the worst
+    # row bucket of the worst column takes share(pr)*share(pc) of the
+    # 2*m_input records a processor row generated
+    cap_r2 = comm_model.plan_cap_route(int(nrec * pc * share_c), pr,
+                                       share_r, slack=route_slack)
+    # can't exceed what hop 1 delivers
+    return cap_r1, min(cap_r2, _round_up(pc * cap_r1, 32))
 
 
 def _route(ru, rv, ok, dest, p_dest: int, cap_route: int, axis: str,
@@ -194,10 +225,11 @@ def dist_build_1d(spec: BuildSpec, p: int, mesh, *, align: int = 128,
                   ) -> Tuple[Blocked1DGraph, Dict[str, Any]]:
     """Device-side distributed build of the 1D row-strip format.
 
-    Bit-identical to ``build_blocked_1d(rmat_graph(..., generator=
-    "counter"), p, align, cap_pad)`` — same edge set, same sort orders,
-    same capacity rounding — but no edge array ever exists on host:
-    only per-shard scalar stats cross the device boundary.
+    Bit-identical to ``build_blocked_1d`` of the counter edge list
+    (``rmat_graph(..., generator="counter")``) relabeled by
+    ``build_relabel(spec, p)`` — same edge set, same sort orders, same
+    capacity rounding — but no edge array ever exists on host: only
+    per-shard scalar stats cross the device boundary.
 
     Each phase program compiles ahead of its run; the seconds go to
     ``compile_spent`` (a fresh list when None), whose sum is
@@ -206,11 +238,14 @@ def dist_build_1d(spec: BuildSpec, p: int, mesh, *, align: int = 128,
     spent = [] if compile_spent is None else compile_spent
     part = make_partition_1d(spec.n, p, align)
     chunk, n_pad = part.chunk, part.n
+    relabel = build_relabel(spec, p)
     m_input = spec.m_input
     m_per = -(-m_input // p)                     # static per-device slice
     nrec = 2 * m_per
-    cap_route = comm_model.plan_cap_route(nrec, p, spec.a, spec.b,
-                                          slack=route_slack)
+    cap_route = comm_model.plan_cap_route(
+        nrec, p, comm_model.relabeled_route_share(p, spec.scale, spec.a,
+                                                  spec.b),
+        slack=route_slack)
     r_buf = p * cap_route
 
     def phase1():
@@ -219,6 +254,8 @@ def dist_build_1d(spec: BuildSpec, p: int, mesh, *, align: int = 128,
         u, v = rmat_edges_counter_jax(spec.scale, m_per, start,
                                       spec.edge_factor, spec.a, spec.b,
                                       spec.c, spec.seed)
+        if relabel is not None:
+            u, v = relabel.apply(u, jnp), relabel.apply(v, jnp)
         in_stream = (jnp.arange(m_per, dtype=jnp.uint32) + start) \
             < jnp.uint32(m_input)
         # symmetrize pre-routing: both directions of every kept edge
@@ -301,7 +338,8 @@ def dist_build_1d(spec: BuildSpec, p: int, mesh, *, align: int = 128,
         edge_src=edge_src, row_idx=row_idx, row_ptr=row_ptr,
         col_idx=col_idx, edge_dst=edge_dst, jc=jc, cp=cp,
         nnz=nnz_d, nzc=nzc_d, deg_A=deg_A,
-        cap=cap, cap_nzc=cap_nzc, maxdeg_col=maxdeg_col, col_ptr=None)
+        cap=cap, cap_nzc=cap_nzc, maxdeg_col=maxdeg_col, col_ptr=None,
+        relabel=relabel)
     info = {
         "build_s": t2 - t0, "gen_route_s": t1 - t0, "format_s": t2 - t1,
         "compile_s": sum(spent),
@@ -327,7 +365,8 @@ def dist_build_2d(spec: BuildSpec, pr: int, pc: int, mesh, *,
                   compile_spent: Optional[List[float]] = None,
                   ) -> Tuple[BlockedGraph, Dict[str, Any]]:
     """Device-side distributed build of the 2D (pr x pc) checkerboard,
-    bit-identical to ``build_blocked`` on the counter edge stream.
+    bit-identical to ``build_blocked`` on the counter edge stream
+    relabeled by ``build_relabel(spec, pr * pc)``.
 
     Owner routing is TWO single-axis hops (column owner along "model",
     then row owner along "data") instead of one p-way exchange — each
@@ -339,19 +378,10 @@ def dist_build_2d(spec: BuildSpec, pr: int, pc: int, mesh, *,
     part = make_partition(spec.n, pr, pc, align)
     nr, nc, chunk, p = part.nr, part.nc, part.chunk, part.p
     n_pad = part.n
+    relabel = build_relabel(spec, p)
     m_input = spec.m_input
     m_per = -(-m_input // p)
-    nrec = 2 * m_per
-    cap_r1 = comm_model.plan_cap_route(nrec, pc, spec.a, spec.b,
-                                       slack=route_slack)
-    # hop 2 buckets the whole column's records by block row: the worst
-    # row bucket of the worst column takes skew(pr)*skew(pc) of the
-    # 2*m_input records a processor row generated
-    rec1 = pc * cap_r1
-    cap_r2 = comm_model.plan_cap_route(
-        int(nrec * pc * comm_model.rmat_strip_skew(pc, spec.a, spec.b)),
-        pr, spec.a, spec.b, slack=route_slack)
-    cap_r2 = min(cap_r2, _round_up(rec1, 32))    # can't exceed hop-1 recv
+    cap_r1, cap_r2 = route_caps_2d(spec, pr, pc, route_slack)
     r_buf = pr * cap_r2
 
     def phase1():
@@ -362,6 +392,8 @@ def dist_build_2d(spec: BuildSpec, pr: int, pc: int, mesh, *,
         u, v = rmat_edges_counter_jax(spec.scale, m_per, start,
                                       spec.edge_factor, spec.a, spec.b,
                                       spec.c, spec.seed)
+        if relabel is not None:
+            u, v = relabel.apply(u, jnp), relabel.apply(v, jnp)
         in_stream = (jnp.arange(m_per, dtype=jnp.uint32) + start) \
             < jnp.uint32(m_input)
         ru = jnp.concatenate([u, v])
@@ -467,7 +499,7 @@ def dist_build_2d(spec: BuildSpec, pr: int, pc: int, mesh, *,
         row_ptr=row_ptr, col_idx=col_idx, edge_dst=edge_dst,
         seg_ptr=seg_ptr, jc=jc, cp=cp, jr=jr, rp=rp,
         nnz=nnz_d, nzc=nzc_d, nzr=nzr_d, deg_A=deg_A,
-        cap=cap, cap_seg=cap_seg, maxdeg_col=maxdeg_col)
+        cap=cap, cap_seg=cap_seg, maxdeg_col=maxdeg_col, relabel=relabel)
     info = {
         "build_s": t2 - t0, "gen_route_s": t1 - t0, "format_s": t2 - t1,
         "compile_s": sum(spent),
@@ -545,9 +577,10 @@ def dist_build(spec: BuildSpec, decomposition: str, mesh, grid,
 # counter stream the device build consumed: ``rmat_edges_counter`` is a
 # pure function of (seed, edge index), and shard contents depend only on
 # the edge subset owned by that shard — so the host twin below filters
-# the full stream down to one shard's edges and replays phases 1+2 with
-# numpy, producing arrays bit-identical to the device build (the store
-# re-checks the stored CRC after regeneration to prove it).
+# the full stream (relabeled as the build relabeled it) down to one
+# shard's edges and replays phases 1+2 with numpy, producing arrays
+# bit-identical to the device build (the store re-checks the stored CRC
+# after regeneration to prove it).
 
 _REGEN_STEP = 1 << 22     # stream chunking: bounds peak host memory
 
@@ -558,16 +591,20 @@ def _pad_i32(vals, cap: int, fill: int = 0) -> np.ndarray:
     return out
 
 
-def _shard_edges(spec: BuildSpec, keep) -> Tuple[np.ndarray, np.ndarray]:
+def _shard_edges(spec: BuildSpec, p: int,
+                 keep) -> Tuple[np.ndarray, np.ndarray]:
     """Deduped (u, v) int64 pairs of the symmetrized self-loop-free
-    stream for which ``keep(u, v)`` holds, sorted by (u, v) — the CSC
-    dedup order of ``_dedup_sorted``."""
+    stream of a ``p``-shard build for which ``keep(u, v)`` holds, sorted
+    by (u, v) — the CSC dedup order of ``_dedup_sorted``."""
     us, vs = [], []
+    relabel = build_relabel(spec, p)
     for s in range(0, spec.m_input, _REGEN_STEP):
         cnt = min(_REGEN_STEP, spec.m_input - s)
         u, v = rmat_edges_counter(spec.scale, spec.edge_factor, spec.a,
                                   spec.b, spec.c, spec.seed, start=s,
                                   count=cnt)
+        if relabel is not None:
+            u, v = relabel.apply(u), relabel.apply(v)
         for a, b in ((u, v), (v, u)):
             mask = (a != b) & keep(a, b)
             if mask.any():
@@ -586,7 +623,7 @@ def regen_shard_1d(spec: BuildSpec, part, k: int, *, cap: int,
     block dim), bit-identical to ``dist_build_1d`` phase 2."""
     chunk, n_pad = part.chunk, part.n
     lo = k * chunk
-    gu, gv = _shard_edges(spec,
+    gu, gv = _shard_edges(spec, part.p,
                           lambda a, b: (b >= lo) & (b < lo + chunk))
     u = gu.astype(np.int32)
     v = (gv - lo).astype(np.int32)
@@ -627,7 +664,7 @@ def regen_shard_2d(spec: BuildSpec, part, i: int, j: int, *, cap: int,
     block dims), bit-identical to ``dist_build_2d`` phase 2."""
     nr, nc, chunk, pc = part.nr, part.nc, part.chunk, part.pc
     gu, gv = _shard_edges(
-        spec, lambda a, b: (a // nc == j) & (b // nr == i))
+        spec, part.p, lambda a, b: (a // nc == j) & (b // nr == i))
     u = (gu - j * nc).astype(np.int32)
     v = (gv - i * nr).astype(np.int32)
     nnz = len(u)
@@ -650,7 +687,7 @@ def regen_shard_2d(spec: BuildSpec, part, i: int, j: int, *, cap: int,
     # chunk — needs edges from EVERY column block of row i
     dlo = i * nr + j * chunk
     du, dv = _shard_edges(
-        spec, lambda a, b: (b >= dlo) & (b < dlo + chunk))
+        spec, part.p, lambda a, b: (b >= dlo) & (b < dlo + chunk))
     deg = (np.bincount((dv - dlo).astype(np.int64),
                        minlength=chunk)[:chunk] if len(dv)
            else np.zeros(chunk, np.int64))

@@ -39,14 +39,14 @@ blow-up Fig. 6-style via ``storage_words("csr")`` vs
 from __future__ import annotations
 
 from dataclasses import dataclass, fields
-from typing import Any, Dict
+from typing import Any, Dict, Optional
 
 import jax
 import numpy as np
 
 from repro.core.partition import (Partition1D, Partition2D, make_partition,
                                   make_partition_1d)
-from repro.graph.rmat import EdgeList
+from repro.graph.rmat import EdgeList, VertexRelabel
 
 
 def _round_up(x: int, q: int) -> int:
@@ -80,6 +80,9 @@ class BlockedGraph:
     cap: int
     cap_seg: int
     maxdeg_col: int       # max CSC column-segment length over all blocks
+    # the bijection the vertex ids were relabeled by before partitioning
+    # (None: stored under the input labels); the search maps through it
+    relabel: Optional[VertexRelabel] = None
 
     # ------------------------------------------------------------------
     def device_arrays(self) -> Dict[str, Any]:
@@ -151,6 +154,7 @@ class Blocked1DGraph:
     cap_nzc: int
     maxdeg_col: int       # max CSC column-segment length over all strips
     col_ptr: "np.ndarray | None" = None   # (p, n+1) i32, the §5.1 blow-up
+    relabel: Optional[VertexRelabel] = None   # as in BlockedGraph
 
     def device_arrays(self) -> Dict[str, Any]:
         out = {}
@@ -177,6 +181,15 @@ class Blocked1DGraph:
             raise ValueError(mode)
         return {"index_i32": idx, "pointer_i32": int(ptr),
                 "total_i32": idx + int(ptr)}
+
+
+def input_degrees(graph) -> np.ndarray:
+    """(n_orig,) out-degree of every vertex by its input label, on host:
+    ``deg_A`` is indexed by the stored ids, relabeled or not."""
+    deg = np.asarray(graph.deg_A).reshape(-1)[: graph.part.n_orig]
+    if graph.relabel is None:
+        return deg
+    return deg[graph.relabel.apply(np.arange(graph.part.n_orig))]
 
 
 def build_blocked_1d(edges: EdgeList, p: int, align: int = 128,

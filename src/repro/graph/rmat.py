@@ -20,6 +20,14 @@ Two generators coexist:
     [k*m/p, (k+1)*m/p) and the union is bit-identical for every p.
     The numpy and jnp implementations are bit-identical (pure uint32
     wrapping arithmetic, thresholds precomputed as Python ints).
+
+The counter stream keeps R-MAT's skew in the vertex ids: high-degree
+vertices sit at low ids, so on a mesh of several shards the first block
+of a 1D or 2D partition holds most of the edges.  ``VertexRelabel`` is
+the seeded bijection the distributed build applies to both endpoints
+whenever the mesh has more than one shard (Graph500's ``scramble`` in
+32-bit form; CombBLAS permutes the matrix the same way before its BFS),
+and the search maps roots in and parents out through it.
 """
 from __future__ import annotations
 
@@ -68,6 +76,62 @@ def _counter_u32_np(idx: np.ndarray, salt: int) -> np.ndarray:
     x = x * np.uint32(0x846CA68B)
     x ^= x >> np.uint32(16)
     return x
+
+
+def _reverse_low_bits(v, bits: int):
+    """The low ``bits`` bits of uint32 ``v`` in reverse order."""
+    for shift, mask in ((1, 0x55555555), (2, 0x33333333),
+                        (4, 0x0F0F0F0F), (8, 0x00FF00FF)):
+        s, m = np.uint32(shift), np.uint32(mask)
+        v = ((v >> s) & m) | ((v & m) << s)
+    v = (v >> np.uint32(16)) | (v << np.uint32(16))
+    return v >> np.uint32(32 - bits)
+
+
+@dataclass(frozen=True)
+class VertexRelabel:
+    """A seeded bijection ``h`` on the vertex ids [0, 2**scale): two
+    rounds of an add, an odd multiply and a reversal of the low
+    ``scale`` bits (Graph500's ``scramble``, in uint32).  Ids outside
+    that range (-1 for "no parent", padded ghost vertices) pass through
+    unchanged, so ``apply`` and ``invert`` are bijections on any padded
+    id range too.  Both take numpy or jnp integer arrays (``xp`` names
+    the module) and return the input's dtype."""
+    scale: int
+    adds: Tuple[int, int]
+    muls: Tuple[int, int]      # odd
+
+    @classmethod
+    def from_seed(cls, scale: int, seed: int) -> "VertexRelabel":
+        if not 1 <= scale <= 30:
+            raise ValueError(f"relabel scale={scale} outside [1, 30]")
+        keys = [_mix_int(int(seed) * 0x2545F491 + k * 0x9E3779B1
+                         + 0x6A09E667) for k in range(4)]
+        return cls(scale=scale, adds=(keys[0], keys[2]),
+                   muls=(keys[1] | 1, keys[3] | 1))
+
+    def _map(self, x, xp, forward: bool):
+        inside = (x >= 0) & (x < (1 << self.scale))
+        v = xp.where(inside, x, 0).astype(np.uint32)
+        if forward:
+            for add, mul in zip(self.adds, self.muls):
+                v = (v + np.uint32(add)) * np.uint32(mul)
+                v = _reverse_low_bits(v, self.scale)
+        else:
+            low = np.uint32((1 << self.scale) - 1)
+            for add, mul in zip(self.adds[::-1], self.muls[::-1]):
+                v = _reverse_low_bits(v, self.scale)
+                v = (v * np.uint32(pow(mul, -1, 1 << 32))
+                     - np.uint32(add)) & low
+        return xp.where(inside, v.astype(x.dtype), x)
+
+    def apply(self, x, xp=np):
+        """h(x): input label -> the label the graph is stored under."""
+        return self._map(x, xp, True)
+
+    def invert(self, x, xp=np):
+        """h^-1(x): stored label -> input label."""
+        return self._map(x, xp, False)
 
 
 def rmat_edges_counter(scale: int, edge_factor: int = 16, a: float = 0.57,
@@ -231,6 +295,15 @@ def rmat_edges_counter_kernel(scale: int, count: int, start,
         interpret=interpret,
     )(start)
     return src, dst
+
+
+def relabel_edges(edges: EdgeList, relabel: VertexRelabel) -> EdgeList:
+    """``edges`` with both endpoints mapped through ``relabel``."""
+    if 1 << relabel.scale != edges.n:
+        raise ValueError(f"relabel of 2**{relabel.scale} ids does not fit "
+                         f"a graph of {edges.n} vertices")
+    return EdgeList(n=edges.n, src=relabel.apply(edges.src),
+                    dst=relabel.apply(edges.dst), m_input=edges.m_input)
 
 
 def preprocess(src: np.ndarray, dst: np.ndarray, n: int,

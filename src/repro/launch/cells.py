@@ -28,6 +28,7 @@ from repro.core import steps as bfs_steps
 from repro.core.engine import plan_for_part
 from repro.core.local_ops import get_local_ops
 from repro.core.partition import make_partition
+from repro.graph.dist_build import BuildSpec, build_relabel
 from repro.graph.sampler import khop_sample
 from repro.models import autoint as ai
 from repro.models import gnn as gnn_mod
@@ -435,9 +436,12 @@ def build_bfs_cell(cfg: BFSConfig, shape: BFSShape, mesh,
                     ({k: sh for k in g_specs}, sh, sh), label, meta)
 
     # the engine's plan layer owns dispatch/validation; cells only need
-    # the abstract program, so they build a graph-less plan
+    # the abstract program, so they build a graph-less plan, relabeled
+    # as dist_build relabels a graph over these p shards
+    relabel = build_relabel(
+        BuildSpec(scale=shape.scale, edge_factor=shape.degree), p)
     plan = plan_for_part(part, cfg, mesh, cap_seg=cap_seg, maxdeg=1024,
-                         n_real_edges=float(m_est))
+                         n_real_edges=float(m_est), relabel=relabel)
     if "pod" in mesh.axis_names and kwargs_get_multiroot(cfg):
         pods = mesh.shape["pod"]
         fn = plan.build_batch_fn("pod")

@@ -475,15 +475,24 @@ def main():
         # host-side edge materialization), round-trip the graph store,
         # and traverse.
         import tempfile
+        from dataclasses import replace
         from repro.ckpt.graph_store import GraphStore, plan_bfs_from_store
         from repro.core.engine import plan_bfs
-        from repro.graph.dist_build import BuildSpec, dist_build
+        from repro.graph.dist_build import (BuildSpec, build_relabel,
+                                            dist_build)
+        from repro.graph.formats import input_degrees
+        from repro.graph.rmat import relabel_edges
 
         spec = BuildSpec(scale=10, edge_factor=16, seed=3)
         edges = rmat_graph(spec.scale, edge_factor=spec.edge_factor,
                            seed=spec.seed, generator="counter")
-        gh1 = build_blocked_1d(edges, n_dev, align=32, cap_pad=32)
-        gh2 = build_blocked(edges, 4, 4, align=32, cap_pad=32)
+        # the host twins relabel the stream as the 16-shard build does
+        relabel = build_relabel(spec, n_dev)
+        relabeled = relabel_edges(edges, relabel)
+        gh1 = replace(build_blocked_1d(relabeled, n_dev, align=32,
+                                       cap_pad=32), relabel=relabel)
+        gh2 = replace(build_blocked(relabeled, 4, 4, align=32, cap_pad=32),
+                      relabel=relabel)
         mesh1 = make_local_mesh_1d(n_dev)
         mesh2 = make_local_mesh(4, 4)
         gd1, _ = dist_build(spec, "1d", mesh1, n_dev, align=32, cap_pad=32)
@@ -518,7 +527,7 @@ def main():
             store, "s18", BFSConfig(decomposition="1d", instrument=False),
             mesh1, expect_spec=spec18)
         res = plan.compile(store=store).run(
-            int(np.argmax(np.asarray(g18.deg_A).ravel())))
+            int(np.argmax(input_degrees(g18))))
         assert int((res.parents >= 0).sum()) > spec18.n // 4
         print("OK born")
     elif mode == "multiroot":

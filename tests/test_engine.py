@@ -3,6 +3,11 @@ decomposition registry (repro.core.decomp): parity with the one-shot
 ``run_bfs`` across the full combo matrix, compile-once/ship-once
 guarantees, plan-validation error paths, and pod-batched multi-source
 runs in both decompositions."""
+import json
+import os
+import subprocess
+import sys
+
 import jax
 import numpy as np
 import pytest
@@ -386,6 +391,122 @@ def test_run_batch_errors(fixed_graph):
     eng_p = plan_bfs(g2, BFSConfig(), make_local_mesh(1, 1, pods=1)).compile()
     with pytest.raises(ValueError, match="do not split evenly"):
         eng_p.run_batch([])
+
+
+# ---------------------------------------------------------------------------
+# Relabeled graphs: roots and parents in input labels
+# ---------------------------------------------------------------------------
+
+
+def test_one_device_program_has_no_relabel(fixed_graph):
+    """A graph stored under its input labels (every one-shard build)
+    compiles the same search as before the relabel existed: the plan
+    carries none, and no op of the program lies under bfs.relabel."""
+    from repro.core import scopes
+    from repro.graph.dist_build import BuildSpec, dist_build
+    spec = BuildSpec(scale=8, edge_factor=8, seed=4)
+    g, _ = dist_build(spec, "2d", make_local_mesh(1, 1), (1, 1),
+                      align=32, cap_pad=32)
+    plan = plan_bfs(g, BFSConfig(decomposition="2d", instrument=False),
+                    make_local_mesh(1, 1))
+    assert plan.statics.relabel is None
+    assert scopes.RELABEL not in set(plan.compile().op_scopes().values())
+
+
+# every dense combo on 4 forced host devices (2d on 2x2 and 1x4, 1d/1ds
+# on four strips), over a graph built under the relabel: each root's
+# parents from run_many (validated on the device), run_batch and, for a
+# broken copy, validate_parents; the oracle is the same program on the
+# relabeled edge list stored as it stands, its parents mapped back
+_RELABEL_MAIN = """
+import json, os, sys
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import numpy as np
+sys.path.insert(0, {bench!r})
+import reference
+from repro.configs.base import BFSConfig
+from repro.core import local_ops
+from repro.core.engine import plan_bfs
+from repro.core.validate import validate_parents
+from dataclasses import replace
+from repro.graph.formats import build_blocked, build_blocked_1d
+from repro.graph.rmat import VertexRelabel, relabel_edges, rmat_graph
+from repro.launch.mesh import make_local_mesh, make_local_mesh_1d
+
+e = rmat_graph(9, edge_factor=8, seed=3)
+h = VertexRelabel.from_seed(9, 3)
+eh = relabel_edges(e, h)
+g_ref = reference.undirected_graph(e.n, e.src, e.dst)
+roots = np.flatnonzero(e.out_degrees())[[0, 7, 41, 60]]
+stored = h.apply(np.arange(e.n))
+out = {{}}
+for dc, lm, st in local_ops.registered_combos():
+    if lm != "dense":
+        continue
+    if dc == "2d":
+        meshes = [(f"{{pr}}x{{pc}}", build_blocked(eh, pr, pc),
+                   make_local_mesh(pr, pc, pods=1))
+                  for pr, pc in ((2, 2), (1, 4))]
+    else:
+        meshes = [("p4", build_blocked_1d(eh, 4),
+                   make_local_mesh_1d(4, pods=1))]
+    cfg = BFSConfig(decomposition=dc, storage=st, instrument=False)
+    rows = []
+    for name, g_stored, mesh in meshes:
+        g = replace(g_stored, relabel=h)
+        eng = plan_bfs(g, cfg, mesh).compile()
+        oracle = plan_bfs(g_stored, cfg, mesh).compile()
+        got = eng.run_many(roots, validate=True)
+        batch = eng.run_batch(roots)
+        for i, (r, res) in enumerate(zip(roots, got)):
+            want = oracle.run(int(stored[r])).parents
+            mapped = h.invert(want[stored])
+            depth = reference.bfs_depths(g_ref, int(r))
+            broken = res.parents.copy()
+            v = int(np.flatnonzero((broken >= 0) & (np.arange(e.n) != r))[0])
+            broken[v] = v
+            rows.append(dict(
+                mesh=name, root=int(r), same=bool(np.array_equal(
+                    res.parents, mapped)),
+                batch=bool(np.array_equal(batch.parents[i], res.parents)),
+                bad=reference.bad_vertices(g_ref, int(r), res.parents, depth),
+                validated=bool(res.validation.ok),
+                caught=not validate_parents(eng, int(r), broken).ok,
+                bu_levels=int((res.level_stats[:, 2] == 1).sum())))
+    out["-".join((dc, lm, st))] = rows
+print(json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def relabeled_runs():
+    here = os.path.dirname(os.path.abspath(__file__))
+    env = dict(os.environ)
+    env.pop("XLA_FLAGS", None)
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(here, "..", "src")]
+        + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
+    code = _RELABEL_MAIN.format(bench=os.path.join(here, "..", "bench"))
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=600, env=env)
+    assert r.returncode == 0, r.stderr
+    return json.loads(r.stdout.splitlines()[-1])
+
+
+@pytest.mark.parametrize("combo", [
+    "-".join(c) for c in local_ops.registered_combos() if c[1] == "dense"])
+def test_relabeled_search_returns_input_labels(relabeled_runs, combo):
+    """On a relabeled graph, search/run_many/run_batch return parents in
+    input labels: the oracle's on the relabeled graph, mapped back, bit
+    for bit; valid under the benchmark reference's rules; validated on
+    the device; and a broken parent is caught there."""
+    rows = relabeled_runs[combo]
+    assert rows
+    for row in rows:
+        assert row["same"] and row["batch"], (combo, row)
+        assert row["bad"] == 0 and row["validated"], (combo, row)
+        assert row["caught"], (combo, row)
+    assert sum(row["bu_levels"] for row in rows) > 0, combo
 
 
 # ---------------------------------------------------------------------------

@@ -103,6 +103,29 @@ def test_graph_round_trip_arrays_and_parents(tmp_path):
     assert ra.n_levels == rb.n_levels
 
 
+def test_relabeled_graph_round_trip(tmp_path):
+    """A graph stored under relabeled ids keeps its relabel through the
+    store, so a loaded session still answers in input labels."""
+    from repro.ckpt.graph_store import GraphStore, plan_bfs_from_store
+    from repro.core.engine import plan_bfs
+    from repro.graph.rmat import VertexRelabel, relabel_edges
+    store = GraphStore(str(tmp_path))
+    edges = rmat_graph(SPEC.scale, edge_factor=SPEC.edge_factor,
+                       seed=SPEC.seed, generator="counter")
+    h = VertexRelabel.from_seed(SPEC.scale, SPEC.seed)
+    g = dataclasses.replace(
+        build_blocked_1d(relabel_edges(edges, h), 1, align=32, cap_pad=32),
+        relabel=h)
+    store.save_graph("r", g, spec=SPEC)
+    assert store.load_graph("r").relabel == h
+    cfg = BFSConfig(decomposition="1d", instrument=False)
+    ra = plan_bfs(g, cfg, _mesh1()).compile().run(5)
+    rb = plan_bfs_from_store(store, "r", cfg, _mesh1(),
+                             expect_spec=SPEC).compile().run(5)
+    assert np.array_equal(ra.parents, rb.parents)
+    assert ra.parents[5] == 5
+
+
 def test_stale_spec_hash_fails_loudly(tmp_path):
     from repro.ckpt.graph_store import GraphStore
     store = GraphStore(str(tmp_path))

@@ -208,6 +208,66 @@ def test_2d_ring_rotation_runs_under_expand(exchange_scopes):
                 (case, mode, sorted(set(bu)))
 
 
+# the timed search of a relabeled graph on 4 forced host devices (2d on
+# 2x2, 1d on four strips), and of the same edges on one device: the
+# compiled instructions under each scope
+_RELABEL_SCOPES_MAIN = """
+import json, os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+from repro.configs.base import BFSConfig
+from repro.core.engine import plan_bfs
+from dataclasses import replace
+from repro.graph.formats import build_blocked, build_blocked_1d
+from repro.graph.rmat import VertexRelabel, relabel_edges, rmat_graph
+from repro.launch.mesh import make_local_mesh, make_local_mesh_1d
+
+e = rmat_graph(8, edge_factor=8, seed=4)
+h = VertexRelabel.from_seed(8, 4)
+eh = relabel_edges(e, h)
+out = {}
+for name, dc, g, mesh in (
+        ("2d-2x2", "2d", replace(build_blocked(eh, 2, 2), relabel=h),
+         make_local_mesh(2, 2)),
+        ("1d-p4", "1d", replace(build_blocked_1d(eh, 4), relabel=h),
+         make_local_mesh_1d(4)),
+        ("2d-1x1", "2d", build_blocked(e, 1, 1), make_local_mesh(1, 1))):
+    eng = plan_bfs(g, BFSConfig(decomposition=dc, instrument=False),
+                   mesh).compile()
+    out[name] = sorted(eng.op_scopes().items())
+print(json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def relabel_scopes():
+    env = dict(os.environ)
+    env.pop("XLA_FLAGS", None)
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["PYTHONPATH"] = os.pathsep.join(
+        [src] + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
+    r = subprocess.run([sys.executable, "-c", _RELABEL_SCOPES_MAIN],
+                       capture_output=True, text=True, timeout=600, env=env)
+    assert r.returncode == 0, r.stderr
+    return json.loads(r.stdout.splitlines()[-1])
+
+
+@pytest.mark.parametrize("name, gathers", [("2d-2x2", 2), ("1d-p4", 1)])
+def test_relabel_runs_under_its_scope(relabel_scopes, name, gathers):
+    """The four-device program of a relabeled graph gathers the parents
+    (one all-gather per mesh axis) and maps them under bfs.relabel,
+    outside every level scope."""
+    found = dict(relabel_scopes[name])
+    under = sorted(k for k, v in found.items() if v == scopes.RELABEL)
+    is_gather = [k.replace("_", "-").startswith("all-gather") for k in under]
+    assert sum(is_gather) == gathers, under
+    assert not all(is_gather), under
+
+
+def test_one_device_program_has_no_relabel_ops(relabel_scopes):
+    found = set(dict(relabel_scopes["2d-1x1"]).values())
+    assert found and scopes.RELABEL not in found
+
+
 def test_build_compile_s_sums_every_attempt(monkeypatch):
     """``info["compile_s"]`` is the phase compiles of every attempt: a
     build whose routing overflows twice compiles phase 1 three times
